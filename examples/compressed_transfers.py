@@ -25,7 +25,6 @@ import jax.numpy as jnp  # noqa: E402
 from jax.sharding import Mesh  # noqa: E402
 
 from repro.configs import get_smoke_config  # noqa: E402
-from repro.distributed.compat import shard_map  # noqa: E402
 from repro.core.costmodel import CompressionConfig  # noqa: E402
 from repro.core.hardware import MBPS_100, env_b  # noqa: E402
 from repro.core.planner import plan_hpp  # noqa: E402
@@ -65,7 +64,7 @@ _ring_mesh = Mesh(np.array(devs), ("r",))
 @jax.jit
 def _ring_hop(q, s):
     f = lambda t: jax.lax.ppermute(t, "r", ring)
-    return shard_map(
+    return jax.shard_map(
         lambda a, b: (f(a), f(b)), mesh=_ring_mesh,
         in_specs=jax.sharding.PartitionSpec(None),
         out_specs=jax.sharding.PartitionSpec(None), check_vma=False)(q, s)

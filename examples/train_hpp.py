@@ -11,7 +11,7 @@ exactly the same code on a TPU slice).
 
 import argparse
 import os
-import sys
+import tempfile
 
 ap = argparse.ArgumentParser()
 ap.add_argument("--full", action="store_true",
@@ -19,19 +19,17 @@ ap.add_argument("--full", action="store_true",
 ap.add_argument("--steps", type=int, default=None)
 args = ap.parse_args()
 
-os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
-
-sys.argv = [sys.argv[0], "--arch", "phi3-mini-3.8b", "--smoke",
-            "--global-batch", "16", "--seq", "128",
-            "--steps", str(args.steps or (200 if args.full else 30)),
-            "--log-every", "5",
-            "--checkpoint-dir", "/tmp/repro_ckpt"]
+argv = ["--arch", "phi3-mini-3.8b", "--smoke", "--devices", "8",
+        "--global-batch", "16", "--seq", "128",
+        "--steps", str(args.steps or (200 if args.full else 30)),
+        "--log-every", "5",
+        "--checkpoint-dir", os.path.join(tempfile.gettempdir(), "repro_ckpt")]
 if args.full:
     # ~100M params: 12 layers x d_model 768 on the phi3-mini skeleton
-    sys.argv += ["--d-model", "768", "--n-layers", "12", "--seq", "256"]
+    argv += ["--d-model", "768", "--n-layers", "12", "--seq", "256"]
 
 from repro.launch.train import main  # noqa: E402
 
-final_loss = main()
+final_loss = main(argv)["loss"]
 assert final_loss < 6.0, f"loss did not improve: {final_loss}"
 print(f"OK: final loss {final_loss:.3f} (started ~ln(vocab)=6.2+)")

@@ -114,3 +114,8 @@ def env_d() -> Cluster:
 
 
 ENVS = {"A": env_a, "B": env_b, "C": env_c, "D": env_d}
+
+
+def env_v5e(n: int) -> Cluster:
+    """``n`` TPU v5e chips on ICI links (one per device of a chip mesh)."""
+    return Cluster((TPU_V5E,) * n, bandwidth=TPU_V5E_ICI_BW)

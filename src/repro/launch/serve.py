@@ -11,28 +11,20 @@ ContinuousBatcher with slot-level admission control:
 
     PYTHONPATH=src python -m repro.launch.serve --arch phi3-mini-3.8b \
         --smoke --devices 8 --continuous --requests 12 --gen 16
+
+``--devices N`` serves on the first N of ``jax.devices()`` (forcing N host
+devices on the CPU backend); without it every device is used.
 """
 
 import argparse
-import os
 
+import numpy as np
 
-def _preparse_devices():
-    ap = argparse.ArgumentParser(add_help=False)
-    ap.add_argument("--devices", type=int, default=0)
-    args, _ = ap.parse_known_args()
-    if args.devices:
-        os.environ.setdefault(
-            "XLA_FLAGS", f"--xla_force_host_platform_device_count={args.devices}")
+import jax
+import jax.numpy as jnp
+from jax.sharding import Mesh
 
-
-_preparse_devices()
-
-import numpy as np  # noqa: E402
-
-import jax  # noqa: E402
-import jax.numpy as jnp  # noqa: E402
-from jax.sharding import Mesh  # noqa: E402
+from repro.launch.devices import select_devices
 
 
 def run_continuous(args, cfg, mesh) -> None:
@@ -42,7 +34,6 @@ def run_continuous(args, cfg, mesh) -> None:
     from repro.core.hardware import Cluster, JETSON_NX, JETSON_TX2, MBPS_100
     from repro.core.planner import plan_serve
     from repro.core.profiler import LayerTable, Profile
-    from repro.distributed.compat import sharded_init
     from repro.distributed.sharding import named
     from repro.runtime.continuous import (ContinuousBatcher,
                                           engine_from_serve_step,
@@ -89,8 +80,8 @@ def run_continuous(args, cfg, mesh) -> None:
                                shard_alloc=plan.shard_alloc,
                                stage=plan.stage)
     key = jax.random.PRNGKey(0)
-    params = sharded_init(lambda k: prepare_params(k, cfg, ss.spec.plan),
-                          named(ss.mesh, ss.param_specs))(key)
+    params = jax.jit(lambda k: prepare_params(k, cfg, ss.spec.plan),
+                     out_shardings=named(ss.mesh, ss.param_specs))(key)
     engine = engine_from_serve_step(ss, params)
 
     B = ss.spec.batch_global
@@ -125,11 +116,18 @@ def run_continuous(args, cfg, mesh) -> None:
     print("done")
 
 
-def main():
+def main(argv=None) -> dict | None:
+    """Run the launcher on ``argv`` (default ``sys.argv[1:]``).
+
+    Lockstep decode returns ``{"tok_s", "logits_finite", "tokens"}``: the
+    decode rate, whether every step's logits were finite, and the
+    ``(T, B)`` token array (prompt then samples)."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="phi3-mini-3.8b")
     ap.add_argument("--smoke", action="store_true")
     ap.add_argument("--devices", type=int, default=0)
+    ap.add_argument("--n-layers", type=int, default=None,
+                    help="cut the depth (widths stay the config's)")
     ap.add_argument("--batch", type=int, default=8)
     ap.add_argument("--prompt-len", type=int, default=16)
     ap.add_argument("--gen", type=int, default=32)
@@ -149,17 +147,18 @@ def main():
     ap.add_argument("--max-slots", type=int, default=4,
                     help="--continuous: per-shard slot cap handed to the "
                          "planner as profile.max_batch")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
 
     from repro.configs import get_config, get_smoke_config
-    from repro.distributed.compat import sharded_init
     from repro.distributed.sharding import named
     from repro.runtime.serve import build_serve_step, prepare_serve_states
     from repro.runtime.train import prepare_params
 
     cfg = (get_smoke_config(args.arch) if args.smoke else get_config(args.arch))
     cfg = cfg.replace(prefix_len=0, mtp_depth=0)
-    devs = jax.devices()
+    if args.n_layers:
+        cfg = cfg.replace(n_layers=args.n_layers)
+    devs = select_devices(args.devices)
     n = len(devs)
     data_axis = max(1, n // 4)
     mesh = Mesh(np.array(devs).reshape(data_axis, n // data_axis),
@@ -174,11 +173,11 @@ def main():
           f"tp={ss.spec.plan.tp} cache={cache_len}")
 
     key = jax.random.PRNGKey(0)
-    params = sharded_init(lambda k: prepare_params(k, cfg, ss.spec.plan),
-                          named(ss.mesh, ss.param_specs))(key)
-    states = sharded_init(lambda: prepare_serve_states(cfg, ss.spec.plan,
-                                                       args.batch, cache_len),
-                          named(ss.mesh, ss.state_specs))()
+    params = jax.jit(lambda k: prepare_params(k, cfg, ss.spec.plan),
+                     out_shardings=named(ss.mesh, ss.param_specs))(key)
+    states = jax.jit(lambda: prepare_serve_states(cfg, ss.spec.plan,
+                                                  args.batch, cache_len),
+                     out_shardings=named(ss.mesh, ss.state_specs))()
 
     rng = np.random.RandomState(0)
     shape = (args.batch, cfg.n_codebooks) if cfg.n_codebooks > 1 else (args.batch,)
@@ -190,8 +189,13 @@ def main():
     tok = jnp.asarray(prompt[0])
     t0 = time.perf_counter()
     skey = key
+    n_bad = jnp.int32(0)
     for pos in range(cache_len - 1):
         logits, states = ss.step_fn(params, tok, jnp.int32(pos), states)
+        n_bad = n_bad + jnp.sum(~jnp.isfinite(logits))
+        if pos == 0:                      # the compile step is not timed
+            jax.block_until_ready(logits)
+            t1 = time.perf_counter()
         if pos + 1 < args.prompt_len:
             tok = jnp.asarray(prompt[pos + 1])
         else:
@@ -200,13 +204,20 @@ def main():
                 skey, jnp.asarray(logits) / args.temperature, axis=-1)
             tok = nxt.astype(jnp.int32)
             seqs.append(np.asarray(tok))
-    dt = time.perf_counter() - t0
-    gen_tokens = args.gen * args.batch
-    print(f"decoded {args.gen} steps x batch {args.batch} in {dt:.1f}s "
-          f"({gen_tokens / dt:.1f} tok/s on CPU-interpret hardware)")
+    jax.block_until_ready(logits)
+    t_end = time.perf_counter()
+    # every step after the first decodes one token per row
+    tok_s = args.batch * (cache_len - 2) / max(t_end - t1, 1e-9)
+    finite = int(n_bad) == 0
+    print(f"decoded {cache_len - 1} steps x batch {args.batch} "
+          f"({args.gen} generated): first step {t1 - t0:.2f}s (compile), "
+          f"then {tok_s:.1f} tok/s on {n} {devs[0].platform} device(s) "
+          f"({devs[0].device_kind})")
+    print(f"logits finite: {finite}")
     out = np.stack(seqs)  # (T, B) or (T, B, CB)
     print("sample sequence 0:", out[:, 0].reshape(out.shape[0], -1)[:, 0][:24], "...")
     print("done")
+    return {"tok_s": tok_s, "logits_finite": finite, "tokens": out}
 
 
 if __name__ == "__main__":

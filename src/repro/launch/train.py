@@ -3,32 +3,20 @@
     PYTHONPATH=src python -m repro.launch.train --arch phi3-mini-3.8b \
         --smoke --devices 8 --steps 50 --global-batch 16 --seq 128
 
-On a real TPU slice the production mesh comes from ``make_production_mesh``;
-on CPU ``--devices N`` forces N host devices (must be set before jax init,
-which this module does first).
+``--devices N`` trains on the first N of ``jax.devices()``; on the CPU
+backend it first forces N host devices, so the same command rehearses an
+N-chip mesh on a CPU host.  Without it every device is used.  The mesh is
+``(data, model)`` with ``--data-axis`` rows (default ``max(1, N // 4)``).
 """
 
 import argparse
-import os
-import sys
 
+import numpy as np
 
-def _preparse_devices():
-    ap = argparse.ArgumentParser(add_help=False)
-    ap.add_argument("--devices", type=int, default=0)
-    args, _ = ap.parse_known_args()
-    if args.devices:
-        os.environ.setdefault(
-            "XLA_FLAGS", f"--xla_force_host_platform_device_count={args.devices}")
+import jax
+from jax.sharding import Mesh
 
-
-_preparse_devices()
-
-import numpy as np  # noqa: E402
-
-import jax  # noqa: E402
-import jax.numpy as jnp  # noqa: E402
-from jax.sharding import Mesh  # noqa: E402
+from repro.launch.devices import select_devices
 
 
 def _steady_tok_s(args, n_compile: int, t0: float, t_warm, t_end: float):
@@ -42,7 +30,13 @@ def _steady_tok_s(args, n_compile: int, t0: float, t_warm, t_end: float):
     return tokens * args.steps / max(t_end - t0, 1e-9)
 
 
-def main():
+def main(argv=None) -> dict:
+    """Run the launcher on ``argv`` (default ``sys.argv[1:]``).
+
+    Returns ``{"loss", "log", "params", "tok_s"}``: the final loss, the
+    ``(step, loss, seconds per step)`` of every logged step (each timed
+    after ``block_until_ready``), the final params and the steady-state
+    token rate."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default=None,
                     help="architecture id (default phi3-mini-3.8b, or the "
@@ -105,8 +99,10 @@ def main():
                          "the next round's gradients (unbiased in the "
                          "telescoping-sum sense); only active with "
                          "--compress")
-    ap.add_argument("--env", default="D", choices=list("ABCD"),
-                    help="edge environment (analytic profile) for --plan; "
+    ap.add_argument("--env", default="D", choices=[*"ABCD", "v5e"],
+                    help="cluster the analytic profile describes for "
+                         "--plan: a paper edge environment A-D, or 'v5e' "
+                         "(one TPU v5e chip per mesh device, ICI links); "
                          "ignored when a valid --profile artifact is given")
     ap.add_argument("--bandwidth", type=float, default=None, metavar="MBPS",
                     help="override the analytic environment's D2D link "
@@ -156,7 +152,7 @@ def main():
                          "latency drifts more than this fraction from its "
                          "baseline (default: off — probe once, keep the "
                          "winner)")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
     events = _parse_events(args.events)
     if args.fail_at is not None:     # old flags kept as sugar
         arg = "" if args.fail_rank is None else str(args.fail_rank)
@@ -207,7 +203,7 @@ def main():
     if overrides:
         cfg = cfg.replace(**overrides)
 
-    devs = jax.devices()
+    devs = select_devices(args.devices)
     n = len(devs)
     data_axis = args.data_axis or max(1, n // 4)
     model_axis = n // data_axis
@@ -219,7 +215,7 @@ def main():
     opt = AdamW(lr=cosine_schedule(args.lr, warmup=min(20, args.steps // 5),
                                    total=args.steps))
     if args.plan:
-        from repro.core.hardware import ENVS
+        from repro.core.hardware import ENVS, env_v5e
         from repro.core.lowering import plan_to_train_step
         from repro.core.planner import plan_hpp
         from repro.core.profiler import (LayerTable, Profile,
@@ -235,7 +231,8 @@ def main():
                   f"{len(prof.cluster.devices)} devices, "
                   f"batches<={max(measured.batch_sizes)} measured)")
         else:
-            cluster = ENVS[args.env]()
+            cluster = (env_v5e(n) if args.env == "v5e"
+                       else ENVS[args.env]())
             if args.bandwidth:
                 from repro.core.hardware import Cluster
                 cluster = Cluster(cluster.devices, args.bandwidth * 1e6 / 8)
@@ -298,7 +295,7 @@ def main():
             print(f"asteroid plan: {lowered.stage} stages periods="
                   f"{lowered.stage_periods} M={lowered.n_micro} "
                   f"K_p={lowered.warmup} predicted latency {plan.latency:.3f}s")
-            return _run_session(session, cfg, args, events)
+            return {"loss": _run_session(session, cfg, args, events)}
         ts, lowered = plan_to_train_step(plan, prof, cfg, mesh, optimizer=opt,
                                          staleness=args.staleness,
                                          double_buffer=args.double_buffer,
@@ -342,6 +339,8 @@ def main():
     # sync path compiles step_fn at step 0; the bounded-staleness path
     # compiles grad_fn (first round) at step 0 and async_step_fn at step 1
     n_compile = 2 if ts.spec.staleness >= 1 else 1
+    log = []
+    t_prev, prev_step = t0, -1
     for step in range(args.steps):
         batch = ts.shard_batch(ds.batch(step, args.global_batch))
         if ts.spec.staleness >= 1:
@@ -369,10 +368,15 @@ def main():
             jax.block_until_ready(params)
             t_warm = time.perf_counter()      # exclude compile from FINAL
         if step % args.log_every == 0 or step == args.steps - 1:
-            dt = time.perf_counter() - t0
-            tput = args.global_batch * args.seq * (step + 1) / dt
+            jax.block_until_ready((params, loss))
+            now = time.perf_counter()
+            step_s = (now - t_prev) / (step - prev_step)
+            t_prev, prev_step = now, step
+            log.append((step, float(loss), step_s))
+            tput = args.global_batch * args.seq * (step + 1) / (now - t0)
             print(f"step {step:5d} loss {float(loss):.4f} "
-                  f"ce {float(metrics['ce']):.4f} tok/s {tput:,.0f}")
+                  f"ce {float(metrics['ce']):.4f} step {step_s:.3f}s "
+                  f"tok/s {tput:,.0f}")
     jax.block_until_ready(params)
     t_end = time.perf_counter()          # before the flush: its one-off jit
     if grad_buf is not None:             # compile must not bias FINAL
@@ -385,7 +389,8 @@ def main():
         print(f"checkpoint saved to {args.checkpoint_dir}")
     print(f"FINAL tok_s={steady:.1f} loss={float(loss):.4f}")
     print("done")
-    return float(loss)
+    return {"loss": float(loss), "log": log, "params": params,
+            "tok_s": steady}
 
 
 def _parse_events(spec: str | None) -> list:

@@ -96,15 +96,20 @@ NO_PARALLEL = ParallelCtx()
 
 
 def _manual_axes() -> tuple:
-    from repro.distributed.compat import manual_axes
-    return manual_axes()
+    """Manual mesh axes of the enclosing shard_map (empty outside one)."""
+    return tuple(jax.sharding.get_abstract_mesh().manual_axes)
+
+
+def pcast_varying(x, axes):
+    """Idempotently mark ``x`` varying over ``axes``.  pcast is a pure
+    type operation — no communication."""
+    need = tuple(a for a in axes if a not in jax.typeof(x).vma)
+    return lax.pcast(x, need, to="varying") if need else x
 
 
 def vary_all(tree: PyTree) -> PyTree:
     """Mark every leaf varying over all manual mesh axes (no-op outside
-    shard_map and on jax without vma typing).  pcast is a pure type
-    operation — no communication."""
-    from repro.distributed.compat import pcast_varying
+    shard_map)."""
     axes = _manual_axes()
     if not axes:
         return tree

@@ -36,13 +36,12 @@ from jax import lax
 from jax.sharding import PartitionSpec as P
 
 from repro.core.schedule import scan_ticks
-from repro.distributed.compat import pcast_varying
 from repro.kernels.quant_transfer import dequantize_op, quantize_op
 from repro.distributed.mesh import MeshPlan
 from repro.models.blocks import apply_period, shard_config
 from repro.models.config import ModelConfig
 from repro.models.model import MTP_WEIGHT
-from repro.models.module import ParallelCtx, vary_all
+from repro.models.module import ParallelCtx, pcast_varying, vary_all
 from repro.models.norms import rmsnorm
 
 from .vocab_parallel import vp_chunked_ce, vp_embed
@@ -114,11 +113,6 @@ def arrange_periods(periods, stage_periods):
 # ---------------------------------------------------------------------------
 # Stage body
 # ---------------------------------------------------------------------------
-
-
-def _vary(x, axes=("stage",)):
-    """Idempotent pcast-to-varying (vma typing helper; no-op on jax 0.4.x)."""
-    return pcast_varying(x, axes)
 
 
 def _stage_fn(periods_local, period_mask_local, x, positions, cfg_local,
@@ -211,11 +205,9 @@ def pipeline_apply(periods_local, period_mask_local, x_micro, positions,
     M = x_micro.shape[0]
     P_st = n_stages
     # P_st == 1 runs the same tick scan (M ticks, identity ppermute): a
-    # dedicated lax.map fast path trips jax 0.4.x's scan replication
-    # checker (its carry-less scan infers mismatched reps), and a single
-    # stage is exactly the degenerate case of the circular pipeline.
-    # A single stage has no boundary transfers to hide, so double
-    # buffering degenerates to the synchronous scan.
+    # single stage is exactly the degenerate case of the circular pipeline.
+    # It has no boundary transfers to hide, so double buffering
+    # degenerates to the synchronous scan.
     if P_st == 1:
         double_buffer = False
     stage = lax.axis_index("stage")
@@ -514,7 +506,7 @@ def spmd_loss_fn(spec: TrainSpec):
         red_axes = ("pod", "data", "stage", "tp")
 
         def allsum(x):
-            return lax.psum(_vary(x, red_axes), red_axes) / plan.tp
+            return lax.psum(pcast_varying(x, red_axes), red_axes) / plan.tp
 
         mtp_sum = jnp.zeros((), jnp.float32)
         if cfg.mtp_depth > 0 and cfg.n_codebooks == 1 and cfg.prefix_len == 0:

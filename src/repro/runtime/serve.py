@@ -25,7 +25,6 @@ from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core.planner import serve_stage_candidates
-from repro.distributed.compat import shard_map
 from repro.distributed.mesh import MeshPlan, mesh_plan, refine_mesh
 from repro.distributed.sharding import (Layout, SERVE_LAYOUT, named,
                                         param_pspecs, state_pspecs)
@@ -316,7 +315,7 @@ def build_prefill_step(cfg: ModelConfig, production_mesh: Mesh, *,
     bspecs = batch_pspecs(cfg)
     logits_spec = P(("pod", "data"), "tp") if cfg.n_codebooks == 1 \
         else P(("pod", "data"), None, "tp")
-    sharded = shard_map(fn, mesh=mesh, in_specs=(pspecs, bspecs),
+    sharded = jax.shard_map(fn, mesh=mesh, in_specs=(pspecs, bspecs),
                         out_specs=logits_spec, check_vma=False)
     step = jax.jit(sharded, in_shardings=(named(mesh, pspecs),
                                           named(mesh, bspecs)))
@@ -388,7 +387,7 @@ def build_serve_step(cfg: ModelConfig, production_mesh: Mesh, *,
             else P(None, None, "tp")
 
     fn = spmd_decode_fn(spec)
-    sharded = shard_map(fn, mesh=mesh,
+    sharded = jax.shard_map(fn, mesh=mesh,
                         in_specs=(pspecs, tok_spec, P(), sspecs),
                         out_specs=(logits_spec, sspecs),
                         check_vma=False)
@@ -452,7 +451,7 @@ def build_slot_serve_step(cfg: ModelConfig, production_mesh: Mesh, *,
         else P(("pod", "data"), None, "tp")
 
     fn = spmd_decode_fn(spec)
-    sharded = shard_map(fn, mesh=mesh,
+    sharded = jax.shard_map(fn, mesh=mesh,
                         in_specs=(pspecs, tok_spec, row_spec, row_spec,
                                   sspecs),
                         out_specs=(logits_spec, sspecs),

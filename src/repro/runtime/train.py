@@ -19,13 +19,13 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.distributed.compat import shard_map, sharded_init
 from repro.distributed.mesh import MeshPlan, mesh_plan, pick_stage_count, refine_mesh
 from repro.distributed.sharding import (Layout, TRAIN_LAYOUT, named,
                                         param_pspecs)
 from repro.kernels.quant_transfer import roundtrip, roundtrip_ef
 from repro.models.config import ModelConfig
 from repro.models.model import init_model
+from repro.models.module import vary_all
 from repro.optim import AdamW
 
 from .pipeline import (TrainSpec, arrange_periods, batch_pspecs, pad_periods,
@@ -435,24 +435,20 @@ def _bucketed_grad_fn(spec: TrainSpec, base_loss, buckets):
     compresses exactly the bytes each device contributes to the AllReduce.
     """
     fmt, tile, ef_on = spec.compress, spec.quant_tile, spec.error_feedback
-    # Differentiating THROUGH the loss psum inside the body scales every
-    # cotangent by the psum's transpose (another psum of the unit seed =
-    # the device count over the reduced axes); undo it once here.  Device
-    # counts are powers of two on every supported mesh, so the division
-    # itself is exact.
-    plan = spec.plan
-    n_dev = plan.pod * plan.data * plan.stage * plan.tp
 
     def fn(params, batch, ef):
+        # Differentiate w.r.t. params already cast varying over every axis:
+        # the transpose of that cast is the psum over each leaf's free axes,
+        # so taking the gradient before it leaves each device's unreduced
+        # local contribution, which the per-bucket psums below then reduce.
         (loss, metrics), grads = jax.value_and_grad(
-            base_loss, has_aux=True)(params, batch)
+            base_loss, has_aux=True)(vary_all(params), batch)
         leaves, treedef = jax.tree_util.tree_flatten(grads)
         new_leaves = list(leaves)
         new_ef = dict(ef)
         for bi, (free, idxs, _sizes) in enumerate(buckets):
             flat = jnp.concatenate(
                 [leaves[i].astype(jnp.float32).reshape(-1) for i in idxs])
-            flat = flat * jnp.float32(1.0 / n_dev)
             if fmt != "none":
                 if ef_on:
                     k = _ef_key(bi)
@@ -494,7 +490,7 @@ def _assemble_train_step(cfg: ModelConfig, production_mesh: Mesh,
 
     spmd = spmd_loss_fn(spec)
     metrics_sp = {"ce": P(), "aux": P(), "mtp": P(), "tokens": P()}
-    sharded_loss = shard_map(spmd, mesh=mesh,
+    sharded_loss = jax.shard_map(spmd, mesh=mesh,
                              in_specs=(pspecs, bspecs),
                              out_specs=(P(), metrics_sp))
 
@@ -574,7 +570,7 @@ def _assemble_bucketed(spec: TrainSpec, mesh: Mesh, optimizer, abstract,
     ef_sp = ef_specs_for(buckets) if use_ef else {}
     ef_sh = named(mesh, ef_sp)
 
-    sharded_grad = shard_map(_bucketed_grad_fn(spec, spmd, buckets),
+    sharded_grad = jax.shard_map(_bucketed_grad_fn(spec, spmd, buckets),
                              mesh=mesh,
                              in_specs=(pspecs, bspecs, ef_sp),
                              out_specs=(P(), metrics_sp, pspecs, ef_sp))
@@ -673,11 +669,11 @@ def init_train_state(key, ts: TrainStep, optimizer: AdamW | None = None):
     optimizer = optimizer or AdamW(lr=1e-3)
     cfg, plan = ts.spec.cfg, ts.spec.plan
     shardings = named(ts.mesh, ts.param_specs)
-    params = sharded_init(lambda k: prepare_params(k, cfg, plan,
-                                                   ts.spec.stage_periods),
-                          shardings)(key)
-    opt_state = sharded_init(optimizer.init,
-                             _opt_shardings(optimizer,
-                                            jax.eval_shape(lambda: params),
-                                            shardings))(params)
+    params = jax.jit(lambda k: prepare_params(k, cfg, plan,
+                                              ts.spec.stage_periods),
+                     out_shardings=shardings)(key)
+    opt_state = jax.jit(optimizer.init,
+                        out_shardings=_opt_shardings(
+                            optimizer, jax.eval_shape(lambda: params),
+                            shardings))(params)
     return params, opt_state
