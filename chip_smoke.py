@@ -4,12 +4,13 @@
     python chip_smoke.py --chips 4    # the pipeline across four chips
 
 One chip: planned training of phi3-mini-3.8b at its published widths with
-the depth cut (``launch/train.py --plan``), the first step's loss against
-the plain model's loss at the highest matmul precision, and lockstep decode
+the depth cut (``launch/train.py --plan``), the losses of its first two
+steps against the plain model's, with one plain gradient and optimizer step
+between them, at the highest matmul precision, and lockstep decode
 (``launch/serve.py``).  Four chips: the planned training path on a
 ``(data=1, model=4)`` mesh, params checked on every chip, and the same loss
-parity against the single-device reference on chip 0.  Weights are random,
-made from a fixed seed; the data is the repo's synthetic LM stream.
+parity.  Weights are random, made from a fixed seed; the data is the repo's
+synthetic LM stream.
 
 Everything runs in this one process, because a chip belongs to one process
 at a time.  The last line of standard output is one JSON object,
@@ -33,34 +34,38 @@ sys.path.insert(0, str(Path(__file__).resolve().parent / "src"))
 
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
+import numpy as np  # noqa: E402
 
 from repro.launch.devices import select_devices  # noqa: E402
 
 ARCH = "phi3-mini-3.8b"
-# Depth for each chip count, and why; the widths stay published.
-# Compiling the train step for a described v5e chip settled these.
-DEPTH = {1: 2, 4: 4}
+# Depth for each chip count, and micro-batches per step; the widths stay
+# published.  The launcher donates params and Adam state to the step.
+# Compiling the step for a described v5e (16 GiB, 15.75 GiB for programs)
+# settled these: see DEPTH_WHY.
+DEPTH = {1: 4, 4: 8}
+N_MICRO = 8
 DEPTH_WHY = {
-    1: "the train step donates neither params nor Adam state, so both are "
-       "live as inputs and as outputs: 4 layers need 7.80 + 7.80 GB plus "
-       "3.80 GB of temporaries, 19.4 GB > 16 GB; 2 layers need 5.08 + 5.08 "
-       "+ 3.26 GB",
-    4: "at 6 or 8 layers the plan splits the periods 3|1|1|1 or 3|2|2|1 and "
-       "every stage holds slots for the largest share, 18.0 GB per chip; at "
-       "4 layers it takes four stages of one layer each, 10.5 GB per chip",
+    1: "4 layers in micro-batches of 1 compile to a 15.46 GB peak; in "
+       "micro-batches of 2 they need 16.03 GiB",
+    4: "the plan splits the 8 periods 3|2|2|1 and every stage holds slots "
+       "for the largest share: in micro-batches of 1 that compiles to a "
+       "12.97 GB peak per chip",
 }
 SEQ, BATCH, STEPS = 2048, 8, 4
 TINY_SEQ = 64
-# First-step loss against the reference, by platform.  The runtime
-# multiplies at the config's default precision, the reference at
+# Step-0 and step-1 losses against the reference, by platform.  The
+# runtime multiplies at the config's default precision, the reference at
 # "highest".  On a TPU the default is single bf16 passes: at these widths
 # and seq 2048, running the whole model in bf16 on the CPU moved the loss
 # by at most 2.4e-4, and a TPU v5e's first step differed from the
-# reference by 9.5e-6, while removing the last layer moved the loss by
-# 2.7e-3 or more.  On the CPU the default is full f32 and the two agree to
-# about 1e-6.  The removed-layer loss is computed in every run and must
-# fall outside the tolerance, so each run shows that the check can see a
-# missing layer.
+# reference by 9.5e-6.  On the CPU the default is full f32 and the two
+# agree to about 1e-6.  Each run also computes two planted faults, which
+# must fall outside the tolerance, so each run shows that the check can
+# see them: the last layer removed (step 0), and the update of every layer
+# but the last withheld, as when the backward pass stops at a stage
+# boundary (step 1).  A gradient wrong only by a constant factor is not
+# among them: global-norm clipping and Adam both divide it out.
 LOSS_TOL = {"tpu": 1e-3, "cpu": 1e-5}
 
 
@@ -105,7 +110,8 @@ def train_phase(n_chips: int, depth: int, seq: int, tiny: bool) -> dict:
 
     argv = ["--plan", "--env", "v5e", "--arch", ARCH,
             "--n-layers", str(depth), "--seq", str(seq),
-            "--global-batch", str(BATCH), "--steps", str(STEPS),
+            "--global-batch", str(BATCH), "--n-micro", str(N_MICRO),
+            "--steps", str(STEPS),
             "--devices", str(n_chips), "--log-every", "1"]
     if tiny:
         argv.append("--smoke")
@@ -146,38 +152,97 @@ def check_params_on_chips(params, devs) -> None:
                            f"on {len(devs)} chips: not one slice per chip")
 
 
-def parity_phase(first_loss: float, depth: int, seq: int,
+def reference_losses(params, b0, b1, cfg, optimizer) -> dict:
+    """The plain model's losses for the launcher's first two steps: step
+    0 on ``params`` and ``b0``, then one gradient and ``optimizer`` step,
+    then step 1 on ``b1``; and the same losses with each planted fault."""
+    from repro.models.model import loss_fn
+
+    def loss(p, b):
+        return loss_fn(p, b, cfg)[0]
+
+    def add_sequence(acc, seq):
+        # one sequence at a time: a batch's backward pass at once would
+        # not fit one chip beside the params and gradients
+        lg = jax.value_and_grad(loss)(params, jax.tree.map(
+            lambda x: x[None], seq))
+        return jax.tree.map(jnp.add, acc, lg), None
+
+    zeros = (jnp.zeros(()), jax.tree.map(jnp.zeros_like, params))
+    (l0, grads), _ = jax.lax.scan(add_sequence, zeros, b0)
+    # every sequence has the same number of targets, so the batch mean is
+    # the mean over sequences
+    l0, grads = jax.tree.map(lambda x: x / BATCH, (l0, grads))
+    p1, _ = optimizer.update(grads, optimizer.init(params), params)
+    removed = {**params, "periods": jax.tree.map(
+        lambda x: x.at[-1].set(0), params["periods"])}
+    frozen = {**p1, "periods": jax.tree.map(
+        lambda new, old: new.at[:-1].set(old[:-1]),
+        p1["periods"], params["periods"])}
+    return {"step 0": l0, "step 1": loss(p1, b1),
+            "step 0, last layer removed": loss(removed, b0),
+            "step 1, only the last layer updated": loss(frozen, b1)}
+
+
+def reference_shardings(abstract, devs):
+    """Each leaf split over ``devs`` along its last axis past the first
+    that divides evenly (never the period-stack axis), else replicated."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    mesh = Mesh(np.array(devs), ("x",))
+
+    def one(leaf):
+        for ax in range(leaf.ndim - 1, 0, -1):
+            if leaf.shape[ax] % len(devs) == 0:
+                return NamedSharding(mesh, P(*[None] * ax, "x"))
+        return NamedSharding(mesh, P())
+
+    return jax.tree.map(one, abstract)
+
+
+def parity_phase(losses, optimizer, devs, depth: int, seq: int,
                  tiny: bool) -> None:
-    """First-step loss against ``models.model.loss_fn`` on the same
-    params (``init_model`` from the launcher's seed) and batch, on the
-    first device at the highest matmul precision."""
+    """The launcher's step-0 and step-1 losses against
+    ``reference_losses`` on the same params (``init_model`` from the
+    launcher's seed), batches and optimizer, at the highest matmul
+    precision.  XLA partitions the reference over the same chips; none of
+    the repo's pipeline code runs in it."""
     from repro.configs import get_config, get_smoke_config
     from repro.data import SyntheticLM
-    from repro.models.model import init_model, loss_fn
+    from repro.models.model import init_model
 
     cfg = (get_smoke_config if tiny else get_config)(ARCH).replace(
         n_layers=depth)
-    batch = {k: jnp.asarray(v) for k, v in
-             SyntheticLM(cfg.vocab_size, seq).batch(0, BATCH).items()}
+    ds = SyntheticLM(cfg.vocab_size, seq)
+    b0, b1 = ({k: jnp.asarray(v) for k, v in ds.batch(i, BATCH).items()}
+              for i in (0, 1))
+    key = jax.random.PRNGKey(0)
+    shardings = reference_shardings(
+        jax.eval_shape(lambda: init_model(key, cfg)), devs)
     with jax.default_matmul_precision("highest"):
-        params = jax.jit(lambda k: init_model(k, cfg))(jax.random.PRNGKey(0))
-        loss = jax.jit(lambda p, b: loss_fn(p, b, cfg)[0])
-        ref = float(loss(params, batch))
-        params["periods"] = jax.tree.map(lambda x: x.at[-1].set(0),
-                                         params["periods"])
-        dropped = float(loss(params, batch))
-    tol = LOSS_TOL[jax.devices()[0].platform]
-    diff, witness = abs(first_loss - ref), abs(dropped - ref)
-    print(f"[parity] first-step loss {first_loss:.6f} reference "
-          f"{ref:.6f} |diff| {diff:.2e} (tolerance {tol:.0e}); "
-          f"last layer removed: |diff| {witness:.2e}", flush=True)
-    if not diff <= tol:
-        raise SmokeFailure(f"first-step loss {first_loss} is {diff:.2e} "
-                           f"from the reference {ref} (> {tol:.0e})")
-    if not witness > tol:
-        raise SmokeFailure(f"removing a layer moves the reference loss by "
-                           f"only {witness:.2e}: the tolerance {tol:.0e} "
-                           "cannot see a missing layer")
+        params = jax.jit(lambda k: init_model(k, cfg),
+                         out_shardings=shardings)(key)
+        ref = jax.jit(lambda p, x, y: reference_losses(p, x, y, cfg,
+                                                       optimizer))(
+            params, b0, b1)
+    ref = {k: float(v) for k, v in ref.items()}
+    tol = LOSS_TOL[devs[0].platform]
+    for step, got in enumerate(losses[:2]):
+        want = ref[f"step {step}"]
+        diff = abs(got - want)
+        print(f"[parity] step {step} loss {got:.6f} reference {want:.6f} "
+              f"|diff| {diff:.2e} (tolerance {tol:.0e})", flush=True)
+        if not diff <= tol:
+            raise SmokeFailure(f"step {step} loss {got} is {diff:.2e} from "
+                               f"the reference {want} (> {tol:.0e})")
+    for fault, clean in (("step 0, last layer removed", "step 0"),
+                         ("step 1, only the last layer updated", "step 1")):
+        witness = abs(ref[fault] - ref[clean])
+        print(f"[parity] {fault}: |diff| {witness:.2e}", flush=True)
+        if not witness > tol:
+            raise SmokeFailure(f"{fault} moves the reference loss by only "
+                               f"{witness:.2e}: the tolerance {tol:.0e} "
+                               "cannot see it")
 
 
 def decode_phase(depth: int, tiny: bool) -> None:
@@ -208,7 +273,8 @@ def main(argv=None) -> None:
 
     counters = _compile_counters()
     devs = select_devices(args.chips)
-    if not args.tiny and devs[0].platform != "tpu":
+    on_tpu = devs[0].platform == "tpu"
+    if not (on_tpu or args.tiny):
         raise SystemExit(f"no TPU: JAX finds {devs[0].platform} devices")
     print(f"[device] {len(devs)} x {devs[0].platform} "
           f"({devs[0].device_kind}) of {len(jax.devices())}", flush=True)
@@ -221,9 +287,9 @@ def main(argv=None) -> None:
     print(f"[train] peak memory per chip: {_peak_memory(devs)}", flush=True)
     if args.chips > 1:
         check_params_on_chips(res["params"], devs)
-    first_loss = res["log"][0][1]
+    losses, optimizer = [loss for _, loss, _ in res["log"]], res["optimizer"]
     del res
-    parity_phase(first_loss, depth, seq, args.tiny)
+    parity_phase(losses, optimizer, devs, depth, seq, args.tiny)
     if args.chips == 1:
         decode_phase(depth, args.tiny)
     print(f"[compile] backend compile {counters['compile_s']:.1f}s, "
@@ -231,12 +297,11 @@ def main(argv=None) -> None:
           f"{counters['cache_misses']}; wall {time.perf_counter() - t0:.1f}s",
           flush=True)
 
-    d = jax.devices()[0]
-    if d.platform != "tpu":
-        raise SystemExit(f"no TPU: the phases ran on {d.platform}")
+    if not on_tpu:
+        raise SystemExit(f"no TPU: the phases ran on {devs[0].platform}")
     print(json.dumps({"ok": True, "device": {
-        "platform": d.platform, "kind": d.device_kind,
-        "count": len(jax.devices())}}))
+        "platform": devs[0].platform, "kind": devs[0].device_kind,
+        "count": len(devs)}}))
 
 
 if __name__ == "__main__":

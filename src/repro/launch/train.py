@@ -33,10 +33,10 @@ def _steady_tok_s(args, n_compile: int, t0: float, t_warm, t_end: float):
 def main(argv=None) -> dict:
     """Run the launcher on ``argv`` (default ``sys.argv[1:]``).
 
-    Returns ``{"loss", "log", "params", "tok_s"}``: the final loss, the
-    ``(step, loss, seconds per step)`` of every logged step (each timed
-    after ``block_until_ready``), the final params and the steady-state
-    token rate."""
+    Returns ``{"loss", "log", "params", "optimizer", "tok_s"}``: the final
+    loss, the ``(step, loss, seconds per step)`` of every logged step (each
+    timed after ``block_until_ready``), the final params, the optimizer
+    with its learning-rate schedule, and the steady-state token rate."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default=None,
                     help="architecture id (default phi3-mini-3.8b, or the "
@@ -341,6 +341,11 @@ def main(argv=None) -> dict:
     n_compile = 2 if ts.spec.staleness >= 1 else 1
     log = []
     t_prev, prev_step = t0, -1
+    # The loop rebinds params and optimizer state every step, so the step
+    # writes its outputs over their buffers; otherwise both are live twice.
+    step_fn = jax.jit(ts.step_fn, donate_argnums=(0, 1))
+    async_step_fn = (jax.jit(ts.async_step_fn, donate_argnums=(0, 1, 2))
+                     if ts.spec.staleness >= 1 else None)
     for step in range(args.steps):
         batch = ts.shard_batch(ds.batch(step, args.global_batch))
         if ts.spec.staleness >= 1:
@@ -354,16 +359,16 @@ def main(argv=None) -> dict:
                     (loss, metrics), grad_buf = ts.grad_fn(params, batch)
             elif bucketed:
                 params, opt_state, grad_buf, ef, loss, metrics = \
-                    ts.async_step_fn(params, opt_state, grad_buf, ef, batch)
+                    async_step_fn(params, opt_state, grad_buf, ef, batch)
             else:
                 params, opt_state, grad_buf, loss, metrics = \
-                    ts.async_step_fn(params, opt_state, grad_buf, batch)
+                    async_step_fn(params, opt_state, grad_buf, batch)
         elif bucketed:
             params, opt_state, ef, loss, metrics = \
-                ts.step_fn(params, opt_state, ef, batch)
+                step_fn(params, opt_state, ef, batch)
         else:
-            params, opt_state, loss, metrics = ts.step_fn(params, opt_state,
-                                                          batch)
+            params, opt_state, loss, metrics = step_fn(params, opt_state,
+                                                       batch)
         if step == n_compile - 1 and args.steps > n_compile:
             jax.block_until_ready(params)
             t_warm = time.perf_counter()      # exclude compile from FINAL
@@ -390,7 +395,7 @@ def main(argv=None) -> dict:
     print(f"FINAL tok_s={steady:.1f} loss={float(loss):.4f}")
     print("done")
     return {"loss": float(loss), "log": log, "params": params,
-            "tok_s": steady}
+            "optimizer": opt, "tok_s": steady}
 
 
 def _parse_events(spec: str | None) -> list:

@@ -46,8 +46,9 @@ def test_select_devices_takes_first_n_and_refuses_more(restore_cache_config):
 
 def test_train_main_plan_matches_reference_loss(restore_cache_config):
     """``launch.train.main(argv)`` through the planner: finite per-step
-    losses, and the first step's loss equals the plain model's loss on
-    the same seed and batch."""
+    losses, the first step's loss equals the plain model's loss on the
+    same seed and batch, and the second step's equals the plain model's
+    after one gradient and optimizer step."""
     from repro.configs import get_smoke_config
     from repro.data import SyntheticLM
     from repro.launch import train
@@ -61,10 +62,17 @@ def test_train_main_plan_matches_reference_loss(restore_cache_config):
     assert math.isfinite(res["loss"]) and res["tok_s"] > 0
 
     cfg = get_smoke_config("phi3-mini-3.8b")
-    batch = {k: jnp.asarray(v)
-             for k, v in SyntheticLM(cfg.vocab_size, 32).batch(0, 4).items()}
-    ref, _ = loss_fn(init_model(jax.random.PRNGKey(0), cfg), batch, cfg)
-    assert abs(res["log"][0][1] - float(ref)) < 1e-5
+    ds = SyntheticLM(cfg.vocab_size, 32)
+    b0, b1 = ({k: jnp.asarray(v) for k, v in ds.batch(i, 4).items()}
+              for i in (0, 1))
+    params = init_model(jax.random.PRNGKey(0), cfg)
+    (ref0, _), grads = jax.value_and_grad(loss_fn, has_aux=True)(
+        params, b0, cfg)
+    opt = res["optimizer"]
+    params, _ = opt.update(grads, opt.init(params), params)
+    ref1, _ = loss_fn(params, b1, cfg)
+    assert abs(res["log"][0][1] - float(ref0)) < 1e-5
+    assert abs(res["log"][1][1] - float(ref1)) < 1e-5
 
 
 def test_serve_main_lockstep_decode(restore_cache_config):
