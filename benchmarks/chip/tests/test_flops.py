@@ -1,0 +1,52 @@
+"""The model-FLOP count against the program's own parameter count."""
+
+import pytest
+
+from benchmarks.chip import flops, harness
+from benchmarks.chip.jobs.train import program_config
+
+CONFIGS = ["phi3-mini-l4", "phi3-mini-l8", "rwkv6-7b-l2"]
+
+
+def load(name):
+    return harness.load_json(harness.HERE / "configs" / f"{name}.json")
+
+
+@pytest.mark.parametrize("name", CONFIGS)
+def test_matmul_params_match_param_count(name):
+    config = load(name)
+    m = config["model"]
+    cfg = program_config(config, m)
+    # param_count = matmul weights + the embedding + two norms per layer
+    want = (cfg.param_count() - cfg.vocab_size * cfg.d_model
+            - 2 * cfg.d_model * cfg.n_layers)
+    assert flops.matmul_params(config["reference"], m) == want
+
+
+@pytest.mark.parametrize("name", CONFIGS)
+def test_train_flops_per_token(name):
+    config = load(name)
+    m, fam = config["model"], config["reference"]
+    cfg = program_config(config, m)
+    seq = 2048
+    dense = 6 * (cfg.param_count() - cfg.vocab_size * cfg.d_model
+                 - 2 * cfg.d_model * cfg.n_layers)
+    if fam == "dense_swiglu":
+        a = cfg.attn
+        mixing = 6 * cfg.n_layers * a.n_heads * a.head_dim * (seq + 1)
+    else:
+        mixing = 12 * cfg.n_layers * cfg.d_model * cfg.rwkv.head_dim
+    assert flops.train_flops_per_token(fam, m, seq) == pytest.approx(
+        dense + mixing, rel=1e-12)
+
+
+def test_phi3_mini_l4_in_gflop():
+    """6 x 551.4 M matmul params + 0.151 GFLOP of causal attention."""
+    got = flops.train_flops_per_token("dense_swiglu",
+                                      load("phi3-mini-l4")["model"], 2048)
+    assert got == pytest.approx(3.45998e9, rel=1e-5)
+
+
+def test_unknown_family_is_an_error():
+    with pytest.raises(ValueError):
+        flops.matmul_params("mamba", load("phi3-mini-l4")["model"])

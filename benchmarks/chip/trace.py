@@ -1,0 +1,220 @@
+"""From a profiler trace to the numbers the per-layer metrics read.
+
+``traced`` records a ``jax.profiler`` trace of a few steps.  ``reduce``
+reads its ``.xplane.pb`` with ``jax.profiler.ProfileData`` and, for each
+chip, over the traced window (the host span ``bench.trace_window``):
+
+* busy time: the union of the intervals of the chip's leaf XLA ops.  Only
+  leaf ops count, here and below: a ``while`` op's event spans the ops of
+  its body and the gaps between them;
+* exposed collective time: the part of the collective ops' intervals in
+  which no other op of that chip runs;
+* op totals by name, and the idle gaps between busy intervals, each named
+  by the innermost ``bench.*`` host span that covers its middle.
+
+The device's clock in the trace can sit a millisecond or more off the
+host's.  Each chip's events are shifted so that its first program starts
+when the first ``bench.dispatch`` span ends: the chips are idle before it,
+so the first step runs as soon as it is dispatched.  The window opens
+then, and closes when the host has waited for the last step.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import glob
+import os
+import shutil
+import tempfile
+
+WINDOW_SPAN = "bench.trace_window"
+DISPATCH_SPAN = "bench.dispatch"
+DEVICE_PLANE = "/device:TPU:"
+OPS_LINE = "XLA Ops"
+MODULES_LINE = "XLA Modules"
+COLLECTIVES = ("all-reduce", "all-gather", "reduce-scatter", "all-to-all",
+               "collective-permute", "send", "recv")
+
+
+def traced(fn, keep_as: str | None = None):
+    """Run ``fn()`` under the profiler.  Returns ``(fn(), Summary)``; the
+    trace file is deleted after reading, unless ``keep_as`` names a path to
+    copy it to."""
+    import jax
+
+    tmp = tempfile.mkdtemp(prefix="chipbench-trace-")
+    try:
+        jax.profiler.start_trace(tmp)
+        try:
+            out = fn()
+        finally:
+            jax.profiler.stop_trace()
+        (path,) = glob.glob(os.path.join(tmp, "**", "*.xplane.pb"),
+                            recursive=True)
+        if keep_as:
+            shutil.copyfile(path, keep_as)
+        return out, reduce(path)
+    finally:
+        shutil.rmtree(tmp, ignore_errors=True)
+
+
+def op_name(event_name: str) -> str:
+    """An XLA op event is named by its HLO text (``%fusion.3 = f32[..]
+    fusion(..), kind=kOutput``); the instruction's name alone is kept."""
+    return event_name.split(" = ", 1)[0].lstrip("%")
+
+
+def is_collective(name: str) -> bool:
+    """Whether an instruction name is a collective's (XLA names each
+    instruction after its opcode: ``all-reduce.1``,
+    ``collective-permute-done.2``)."""
+    n = name.lower()
+    return any(n.startswith(c) for c in COLLECTIVES)
+
+
+def union(intervals):
+    """Merge ``(start, end)`` pairs into sorted disjoint intervals."""
+    out = []
+    for s, e in sorted(intervals):
+        if out and s <= out[-1][1]:
+            if e > out[-1][1]:
+                out[-1][1] = e
+        else:
+            out.append([s, e])
+    return [(s, e) for s, e in out]
+
+
+def leaves(events):
+    """The ``(start, end, name)`` events that contain no other event."""
+    out, stack = [], []          # stack entries: [start, end, name, parent]
+    for ev in sorted(events, key=lambda x: (x[0], -x[1])):
+        while stack and stack[-1][1] <= ev[0]:
+            top = stack.pop()
+            if not top[3]:
+                out.append(tuple(top[:3]))
+        if stack and ev[1] <= stack[-1][1]:
+            stack[-1][3] = True
+        stack.append([*ev, False])
+    out += [tuple(top[:3]) for top in stack if not top[3]]
+    return out
+
+
+def total(intervals) -> float:
+    return float(sum(e - s for s, e in intervals))
+
+
+def subtract(a, b):
+    """Parts of the disjoint sorted intervals ``a`` not covered by ``b``."""
+    out, j = [], 0
+    for s, e in a:
+        cur = s
+        while j < len(b) and b[j][1] <= cur:
+            j += 1
+        k = j
+        while k < len(b) and b[k][0] < e:
+            if b[k][0] > cur:
+                out.append((cur, b[k][0]))
+            cur = max(cur, b[k][1])
+            k += 1
+        if cur < e:
+            out.append((cur, e))
+    return out
+
+
+@dataclasses.dataclass
+class Chip:
+    name: str
+    busy_ns: float
+    exposed_collective_ns: float
+    op_ns: dict                   # op name -> summed duration in the window
+    gaps: list                    # (start, end) idle intervals in the window
+
+
+@dataclasses.dataclass
+class Summary:
+    window_ns: float
+    chips: list
+    host_spans: list              # (start, end, name) of bench.* spans
+
+    @property
+    def busy_s(self) -> float:
+        """Busy seconds, averaged over the chips."""
+        return sum(c.busy_ns for c in self.chips) / len(self.chips) / 1e9
+
+    def idle_share(self) -> float:
+        """Largest idle share of the window over the chips."""
+        return max(1.0 - c.busy_ns / self.window_ns for c in self.chips)
+
+    def top_ops(self, n: int = 10) -> list:
+        tot: dict = {}
+        for c in self.chips:
+            for k, v in c.op_ns.items():
+                tot[k] = tot.get(k, 0.0) + v
+        top = sorted(tot.items(), key=lambda kv: -kv[1])[:n]
+        return [[k, v / len(self.chips) / 1e9] for k, v in top]
+
+    def longest_gaps(self, n: int = 10) -> list:
+        gaps = sorted(((e - s, s, e) for c in self.chips for s, e in c.gaps),
+                      reverse=True)[:n]
+        return [[self.host_activity((s + e) / 2), d / 1e9]
+                for d, s, e in gaps]
+
+    def host_activity(self, t: float) -> str:
+        """The innermost ``bench.*`` span that covers time ``t``."""
+        best = None
+        for s, e, name in self.host_spans:
+            if s <= t <= e and (best is None or e - s < best[1] - best[0]):
+                best = (s, e, name)
+        return best[2] if best else "outside any bench span"
+
+
+def chip_summary(name: str, events, window) -> Chip:
+    """One chip's numbers from its ``(start, end, op name)`` events, on the
+    host's clock, over ``window = (start, end)``."""
+    w0, w1 = window
+    ops, comm, op_ns = [], [], {}
+    for s, e, op in leaves(events):
+        s, e = max(s, w0), min(e, w1)
+        if e <= s:
+            continue
+        op_ns[op] = op_ns.get(op, 0.0) + (e - s)
+        (comm if is_collective(op) else ops).append((s, e))
+    busy = union(ops + comm)
+    exposed = subtract(union(comm), union(ops))
+    return Chip(name, total(busy), total(exposed), op_ns,
+                subtract([(w0, w1)], busy))
+
+
+def reduce(path: str) -> Summary:
+    from jax.profiler import ProfileData
+
+    pd = ProfileData.from_file(path)
+    spans = []
+    for plane in pd.planes:
+        if plane.name.startswith(DEVICE_PLANE):
+            continue
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.startswith("bench."):
+                    spans.append((ev.start_ns, ev.end_ns, ev.name))
+    windows = [(s, e) for s, e, n in spans if n == WINDOW_SPAN]
+    if len(windows) != 1:
+        raise ValueError(f"{len(windows)} {WINDOW_SPAN} spans in {path}")
+    w0, w1 = windows[0]
+    dispatched = min((e for s, e, n in spans if n == DISPATCH_SPAN),
+                     default=w0)
+
+    chips = []
+    for plane in sorted(pd.planes, key=lambda p: p.name):
+        if not plane.name.startswith(DEVICE_PLANE):
+            continue
+        lines = {line.name: list(line.events) for line in plane.lines}
+        first = min((ev.start_ns for ev in lines.get(MODULES_LINE, [])),
+                    default=dispatched)
+        shift = dispatched - first
+        events = [(ev.start_ns + shift, ev.end_ns + shift, op_name(ev.name))
+                  for ev in lines.get(OPS_LINE, [])]
+        chips.append(chip_summary(plane.name, events, (w0, w1)))
+    if not chips:
+        raise ValueError(f"no {DEVICE_PLANE}* plane in {path}")
+    return Summary(float(w1 - w0), chips, spans)
