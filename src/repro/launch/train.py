@@ -176,6 +176,7 @@ def main(argv=None) -> dict:
     from repro.data import SyntheticLM
     from repro.models.frontend import frontend_dim
     from repro.optim import AdamW, cosine_schedule
+    from repro.runtime.pipeline import slot_counts
     from repro.runtime.train import build_train_step, init_train_state
 
     # a --profile artifact supplies the model/seq it was measured for;
@@ -313,8 +314,9 @@ def main(argv=None) -> dict:
                               quant_tile=args.quant_tile,
                               bucket_mb=args.bucket_mb,
                               error_feedback=args.error_feedback)
+    real, computed = slot_counts(ts.spec)
     print(f"plan: stage={ts.spec.plan.stage} tp={ts.spec.plan.tp} "
-          f"M={ts.spec.n_micro} shard_alloc="
+          f"M={ts.spec.n_micro} slots {real}/{computed} shard_alloc="
           f"{ts.spec.shard_alloc or 'uniform'} "
           f"staleness={ts.spec.staleness} "
           f"double_buffer={ts.spec.double_buffer} "

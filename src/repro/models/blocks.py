@@ -20,6 +20,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from repro import scopes
+
 from .attention import (AttentionConfig, attention_decode, attention_forward,
                         init_attention, init_attention_cache)
 from .config import LayerSpec, ModelConfig
@@ -111,7 +113,9 @@ def apply_layer(params, x, positions, cfg: ModelConfig, spec: LayerSpec,
     aux = jnp.zeros((), jnp.float32)
     h = rmsnorm(params["norm1"], x, eps, zc)
     if spec.kind == "attn":
-        h = attention_forward(params["attn"], h, positions, _attn_cfg(cfg, spec), ctx)
+        with scopes.scope(scopes.ATTENTION):
+            h = attention_forward(params["attn"], h, positions,
+                                  _attn_cfg(cfg, spec), ctx)
     elif spec.kind == "mamba":
         h, _ = mamba_forward(params["mamba"], h, cfg.mamba, ctx)
     elif spec.kind == "rwkv":
@@ -124,7 +128,8 @@ def apply_layer(params, x, positions, cfg: ModelConfig, spec: LayerSpec,
         return x, aux
     h = rmsnorm(params["norm2"], x, eps, zc)
     if spec.mlp == "mlp":
-        h = mlp(params["mlp"], h, act=cfg.act, ctx=ctx)
+        with scopes.scope(scopes.MLP):
+            h = mlp(params["mlp"], h, act=cfg.act, ctx=ctx)
     elif spec.mlp == "moe":
         h, aux = moe(params["moe"], h, cfg.moe, cfg.moe.n_experts_global or cfg.moe.n_experts, ctx)
     elif spec.mlp == "rwkv_cm":

@@ -35,6 +35,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
+from repro import scopes
 from repro.core.schedule import scan_ticks
 from repro.kernels.quant_transfer import dequantize_op, quantize_op
 from repro.distributed.mesh import MeshPlan
@@ -84,6 +85,22 @@ def stage_period_mask(stage_periods) -> list[float]:
     return mask
 
 
+def slot_counts(spec: TrainSpec) -> tuple[int, int]:
+    """Period x micro-batch slots of one step: ``(real, computed)``.
+
+    Real slots are the model's periods times the micro-batches.  Every
+    stage computes its padded share ``k`` of the period stack (the largest
+    stage's, ``arrange_periods``/``pad_periods``) on every tick of the scan,
+    filled or not, so the chips compute ``stages x k x ticks`` slots."""
+    n_stages = spec.plan.stage
+    if spec.stage_periods is not None:
+        k = max(j - i for i, j in spec.stage_periods)
+    else:
+        k = -(-spec.cfg.n_periods // n_stages)
+    ticks = scan_ticks(n_stages, spec.n_micro, spec.double_buffer)
+    return spec.cfg.n_periods * spec.n_micro, n_stages * k * ticks
+
+
 def arrange_periods(periods, stage_periods):
     """Arrange stacked period params for a planner-chosen (possibly
     heterogeneous) stage split.
@@ -126,10 +143,12 @@ def _stage_fn(periods_local, period_mask_local, x, positions, cfg_local,
         return vary_all((h, aux + a * valid)), None
 
     fn = jax.checkpoint(body) if remat else body
-    # params are stage-varying (and MoE aux data-varying), so the carry is
-    # typed varying over all manual axes
-    (x, aux) = vary_all((x, jnp.zeros((), jnp.float32)))
-    (x, aux), _ = lax.scan(fn, (x, aux), (periods_local, period_mask_local))
+    with scopes.scope(scopes.STAGE):
+        # params are stage-varying (and MoE aux data-varying), so the carry
+        # is typed varying over all manual axes
+        (x, aux) = vary_all((x, jnp.zeros((), jnp.float32)))
+        (x, aux), _ = lax.scan(fn, (x, aux),
+                               (periods_local, period_mask_local))
     return x, aux
 
 
@@ -213,11 +232,11 @@ def pipeline_apply(periods_local, period_mask_local, x_micro, positions,
     stage = lax.axis_index("stage")
     perm = tuple((i, (i + 1) % P_st) for i in range(P_st))
     hop = 2 if double_buffer else 1
-    if compress != "none" and P_st > 1:
-        def boundary(x):
-            return compressed_ppermute(x, perm, compress, quant_tile)
-    else:
-        def boundary(x):
+
+    def boundary(x):
+        with scopes.scope(scopes.BOUNDARY):
+            if compress != "none" and P_st > 1:
+                return compressed_ppermute(x, perm, compress, quant_tile)
             return lax.ppermute(x, "stage", perm)
 
     state0, outs0, aux0 = vary_all(
@@ -266,8 +285,9 @@ def pipeline_apply(periods_local, period_mask_local, x_micro, positions,
 
         carry0 = (state0, outs0, aux0)
 
-    final, _ = lax.scan(tick, carry0,
-                        jnp.arange(scan_ticks(P_st, M, double_buffer)))
+    with scopes.scope(scopes.PIPELINE):
+        final, _ = lax.scan(tick, carry0,
+                            jnp.arange(scan_ticks(P_st, M, double_buffer)))
     outs, aux = final[-2], final[-1]
     return outs, aux
 
@@ -364,7 +384,8 @@ def spmd_loss_fn(spec: TrainSpec):
         # yields exactly one all-reduce per parameter per step (measured
         # 27.7 GiB -> ~2 GiB per device per step, phi3-mini train_4k).
         if spec.hoist_varying:
-            params = vary_all(params)
+            with scopes.scope(scopes.GRAD_REDUCE):
+                params = vary_all(params)
         tokens = batch["tokens"]
         B_loc = tokens.shape[0]
         S = tokens.shape[-1]
@@ -384,14 +405,15 @@ def spmd_loss_fn(spec: TrainSpec):
             sample_valid = None
 
         # ---- embed (vocab-parallel over tp) -----------------------------
-        if cfg.n_codebooks > 1:
-            x = sum(vp_embed(params["embed"][cb], tokens[:, cb], ctx)
-                    for cb in range(cfg.n_codebooks))
-        else:
-            x = vp_embed(params["embed"], tokens, ctx)
-        if cfg.embed_scale:
-            x = x * jnp.asarray(cfg.d_model ** 0.5, x.dtype)
-        x = x.astype(cfg.cdtype)
+        with scopes.scope(scopes.EMBED):
+            if cfg.n_codebooks > 1:
+                x = sum(vp_embed(params["embed"][cb], tokens[:, cb], ctx)
+                        for cb in range(cfg.n_codebooks))
+            else:
+                x = vp_embed(params["embed"], tokens, ctx)
+            if cfg.embed_scale:
+                x = x * jnp.asarray(cfg.d_model ** 0.5, x.dtype)
+            x = x.astype(cfg.cdtype)
 
         if cfg.prefix_len > 0:
             px = (batch["prefix"].astype(cfg.cdtype) @ params["prefix_proj"])
@@ -426,7 +448,8 @@ def spmd_loss_fn(spec: TrainSpec):
         if spec.hoist_varying:
             # same hoist for the micro-batch buffer: its cotangent (the
             # embedding-gradient path) is reduced once instead of per tick
-            x_micro = vary_all(x_micro)
+            with scopes.scope(scopes.EMBED):
+                x_micro = vary_all(x_micro)
         outs, aux = pipeline_apply(params["periods"], mask_local,
                                    x_micro, positions, cfg_local, ctx,
                                    plan.stage, spec.remat,
@@ -445,105 +468,109 @@ def spmd_loss_fn(spec: TrainSpec):
         chunk = -(-M // P_st)                      # micro-batches per stage
         start = stage * chunk
         if P_st > 1:
-            pad_rows = chunk * P_st - M
-            outs_p = jnp.pad(outs, ((0, pad_rows),) + ((0, 0),) * (outs.ndim - 1)) \
-                if pad_rows else outs
-            recv = lax.all_to_all(outs_p, "stage", split_axis=0, concat_axis=0,
-                                  tiled=True)
-            my = lax.slice_in_dim(recv, (P_st - 1) * chunk, P_st * chunk, axis=0)
+            with scopes.scope(scopes.REDISTRIBUTE):
+                pad_rows = chunk * P_st - M
+                outs_p = jnp.pad(outs, ((0, pad_rows),)
+                                 + ((0, 0),) * (outs.ndim - 1)) \
+                    if pad_rows else outs
+                recv = lax.all_to_all(outs_p, "stage", split_axis=0,
+                                      concat_axis=0, tiled=True)
+                my = lax.slice_in_dim(recv, (P_st - 1) * chunk,
+                                      P_st * chunk, axis=0)
         else:
             my = outs
         # ownership mask: rows past M (padding) contribute nothing
         own = (jnp.arange(chunk) + start) < M
 
-        h = my.reshape(chunk * mb, S_tot, cfg.d_model)
-        own_rows = jnp.repeat(own, mb)
-        h = rmsnorm(params["final_norm"], h, cfg.norm_eps, cfg.zero_centered_norm)
-        if cfg.prefix_len > 0:
-            h_txt = h[:, cfg.prefix_len:]
-        else:
-            h_txt = h
+        with scopes.scope(scopes.HEAD_CE):
+            h = my.reshape(chunk * mb, S_tot, cfg.d_model)
+            own_rows = jnp.repeat(own, mb)
+            h = rmsnorm(params["final_norm"], h, cfg.norm_eps, cfg.zero_centered_norm)
+            if cfg.prefix_len > 0:
+                h_txt = h[:, cfg.prefix_len:]
+            else:
+                h_txt = h
 
-        # ---- targets for this device's chunk -----------------------------
-        tok_m = tokens.reshape(M, mb, *tokens.shape[1:])
-        tok_my = lax.dynamic_slice_in_dim(tok_m, start, chunk, axis=0)
-        tok_my = tok_my.reshape(chunk * mb, *tokens.shape[1:])
+            # ---- targets for this device's chunk -----------------------------
+            tok_m = tokens.reshape(M, mb, *tokens.shape[1:])
+            tok_my = lax.dynamic_slice_in_dim(tok_m, start, chunk, axis=0)
+            tok_my = tok_my.reshape(chunk * mb, *tokens.shape[1:])
 
-        def head_w(cb=None):
-            if cfg.tie_embeddings:
-                w = params["embed"]
-                return (w[cb] if cb is not None else w).T
-            w = params["head"]
-            return w[cb] if cb is not None else w
+            def head_w(cb=None):
+                if cfg.tie_embeddings:
+                    w = params["embed"]
+                    return (w[cb] if cb is not None else w).T
+                w = params["head"]
+                return w[cb] if cb is not None else w
 
-        row_mask = own_rows.astype(jnp.float32)
-        if sample_valid is not None:
-            # rows are (micro-batch chunk, sample slot): slots past this
-            # shard's y_d are padding and contribute nothing to loss, count,
-            # or (through the masked CE's transpose) gradients
-            row_mask = row_mask * jnp.tile(sample_valid, chunk)
-        if cfg.n_codebooks > 1:
-            loss_sum = jnp.zeros((), jnp.float32)
-            cnt_sum = jnp.zeros((), jnp.float32)
-            for cb in range(cfg.n_codebooks):
-                tgt = tok_my[:, cb, 1:]
+            row_mask = own_rows.astype(jnp.float32)
+            if sample_valid is not None:
+                # rows are (micro-batch chunk, sample slot): slots past this
+                # shard's y_d are padding and contribute nothing to loss, count,
+                # or (through the masked CE's transpose) gradients
+                row_mask = row_mask * jnp.tile(sample_valid, chunk)
+            if cfg.n_codebooks > 1:
+                loss_sum = jnp.zeros((), jnp.float32)
+                cnt_sum = jnp.zeros((), jnp.float32)
+                for cb in range(cfg.n_codebooks):
+                    tgt = tok_my[:, cb, 1:]
+                    msk = row_mask[:, None] * jnp.ones_like(tgt, jnp.float32)
+                    l, c = vp_chunked_ce(h_txt[:, :-1], head_w(cb), tgt, msk, ctx,
+                                         cfg.logit_softcap, spec.ce_chunk,
+                                         v_valid=cfg.vocab_size)
+                    loss_sum, cnt_sum = loss_sum + l, cnt_sum + c
+            else:
+                tgt = tok_my[:, 1:]
                 msk = row_mask[:, None] * jnp.ones_like(tgt, jnp.float32)
-                l, c = vp_chunked_ce(h_txt[:, :-1], head_w(cb), tgt, msk, ctx,
-                                     cfg.logit_softcap, spec.ce_chunk,
-                                     v_valid=cfg.vocab_size)
-                loss_sum, cnt_sum = loss_sum + l, cnt_sum + c
-        else:
-            tgt = tok_my[:, 1:]
-            msk = row_mask[:, None] * jnp.ones_like(tgt, jnp.float32)
-            loss_sum, cnt_sum = vp_chunked_ce(h_txt[:, :-1], head_w(), tgt, msk,
-                                              ctx, cfg.logit_softcap,
-                                              spec.ce_chunk, v_valid=cfg.vocab_size)
+                loss_sum, cnt_sum = vp_chunked_ce(h_txt[:, :-1], head_w(), tgt, msk,
+                                                  ctx, cfg.logit_softcap,
+                                                  spec.ce_chunk, v_valid=cfg.vocab_size)
 
-        # ---- MTP (DeepSeek-V3) on the stage-sharded chunk ------------------
-        # values are numerically tp-invariant (psum_tp'd inside) but may be
-        # *marked* tp-varying by vscan; reduce over all axes and divide out
-        # the tp replication so outputs are fully invariant (out_specs P()).
-        red_axes = ("pod", "data", "stage", "tp")
+            # ---- MTP (DeepSeek-V3) on the stage-sharded chunk ------------------
+            # values are numerically tp-invariant (psum_tp'd inside) but may be
+            # *marked* tp-varying by vscan; reduce over all axes and divide out
+            # the tp replication so outputs are fully invariant (out_specs P()).
+            red_axes = ("pod", "data", "stage", "tp")
 
-        def allsum(x):
-            return lax.psum(pcast_varying(x, red_axes), red_axes) / plan.tp
+            def allsum(x):
+                return lax.psum(pcast_varying(x, red_axes), red_axes) / plan.tp
 
-        mtp_sum = jnp.zeros((), jnp.float32)
-        if cfg.mtp_depth > 0 and cfg.n_codebooks == 1 and cfg.prefix_len == 0:
-            m = params["mtp"]
-            emb = vp_embed(params["embed"], tok_my, ctx).astype(cfg.cdtype)
-            e = jnp.concatenate([emb[:, 1:], jnp.zeros_like(emb[:, :1])], axis=1)
-            zc = cfg.zero_centered_norm
-            hh = jnp.concatenate([
-                rmsnorm(m["norm_e"], e, cfg.norm_eps, zc),
-                rmsnorm(m["norm_h"], h_txt, cfg.norm_eps, zc)], axis=-1)
-            hh = (hh @ m["combine"]).astype(cfg.cdtype)
-            pos2 = jnp.broadcast_to(jnp.arange(S_tot, dtype=jnp.int32),
-                                    (hh.shape[0], S_tot))
-            hh, _ = apply_period(m["block"], hh, pos2, cfg_local, ctx)
-            hh = rmsnorm(m["final_norm"], hh, cfg.norm_eps, zc)
-            tgt2 = jnp.concatenate([tok_my[:, 2:], jnp.zeros_like(tok_my[:, :2])],
-                                   axis=1)
-            msk2 = row_mask[:, None] * (jnp.arange(S_tot) < S_tot - 2)[None, :]
-            l2, c2 = vp_chunked_ce(hh, head_w(), tgt2, msk2.astype(jnp.float32),
-                                   ctx, cfg.logit_softcap, spec.ce_chunk,
-                                   v_valid=cfg.vocab_size)
-            mtp_sum = l2 / jnp.maximum(allsum(c2), 1.0)
+            mtp_sum = jnp.zeros((), jnp.float32)
+            if cfg.mtp_depth > 0 and cfg.n_codebooks == 1 and cfg.prefix_len == 0:
+                m = params["mtp"]
+                emb = vp_embed(params["embed"], tok_my, ctx).astype(cfg.cdtype)
+                e = jnp.concatenate([emb[:, 1:], jnp.zeros_like(emb[:, :1])], axis=1)
+                zc = cfg.zero_centered_norm
+                hh = jnp.concatenate([
+                    rmsnorm(m["norm_e"], e, cfg.norm_eps, zc),
+                    rmsnorm(m["norm_h"], h_txt, cfg.norm_eps, zc)], axis=-1)
+                hh = (hh @ m["combine"]).astype(cfg.cdtype)
+                pos2 = jnp.broadcast_to(jnp.arange(S_tot, dtype=jnp.int32),
+                                        (hh.shape[0], S_tot))
+                hh, _ = apply_period(m["block"], hh, pos2, cfg_local, ctx)
+                hh = rmsnorm(m["final_norm"], hh, cfg.norm_eps, zc)
+                tgt2 = jnp.concatenate([tok_my[:, 2:], jnp.zeros_like(tok_my[:, :2])],
+                                       axis=1)
+                msk2 = row_mask[:, None] * (jnp.arange(S_tot) < S_tot - 2)[None, :]
+                l2, c2 = vp_chunked_ce(hh, head_w(), tgt2, msk2.astype(jnp.float32),
+                                       ctx, cfg.logit_softcap, spec.ce_chunk,
+                                       v_valid=cfg.vocab_size)
+                mtp_sum = l2 / jnp.maximum(allsum(c2), 1.0)
 
-        # ---- global reduction ---------------------------------------------
+            # ---- global reduction ---------------------------------------------
 
-        loss_sum = allsum(loss_sum)
-        cnt_sum = allsum(cnt_sum)
-        # aux: sum over stages (layers), mean over dp replicas AND over the
-        # M micro-batches (each tick computes a mean-style aux estimate)
-        aux = allsum(aux) / (plan.dp_shards * M)
-        ce = loss_sum / jnp.maximum(cnt_sum, 1.0)
-        loss = ce + aux
-        if cfg.mtp_depth > 0 and cfg.n_codebooks == 1 and cfg.prefix_len == 0:
-            mtp = allsum(mtp_sum)
-            loss = loss + MTP_WEIGHT * mtp
-        else:
-            mtp = jnp.zeros(())
+            loss_sum = allsum(loss_sum)
+            cnt_sum = allsum(cnt_sum)
+            # aux: sum over stages (layers), mean over dp replicas AND over the
+            # M micro-batches (each tick computes a mean-style aux estimate)
+            aux = allsum(aux) / (plan.dp_shards * M)
+            ce = loss_sum / jnp.maximum(cnt_sum, 1.0)
+            loss = ce + aux
+            if cfg.mtp_depth > 0 and cfg.n_codebooks == 1 and cfg.prefix_len == 0:
+                mtp = allsum(mtp_sum)
+                loss = loss + MTP_WEIGHT * mtp
+            else:
+                mtp = jnp.zeros(())
         metrics = {"ce": ce, "aux": aux, "mtp": mtp, "tokens": cnt_sum}
         return loss, metrics
 

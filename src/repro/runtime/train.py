@@ -17,8 +17,10 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
+from jax.profiler import TraceAnnotation
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from repro import scopes
 from repro.distributed.mesh import MeshPlan, mesh_plan, pick_stage_count, refine_mesh
 from repro.distributed.sharding import (Layout, TRAIN_LAYOUT, named,
                                         param_pspecs)
@@ -144,10 +146,11 @@ class TrainStep:
         session's re-lowered step re-packs for the survivors' allocation
         with no change at the call site."""
         from repro.data import pack_batch, shard_batch
-        if self.spec.shard_alloc is not None:
-            batch_np = pack_batch(batch_np, self.spec.shard_alloc,
-                                  self.spec.n_micro)
-        return shard_batch(batch_np, self.mesh, self.batch_specs)
+        with TraceAnnotation(scopes.SHARD_BATCH_SPAN):
+            if self.spec.shard_alloc is not None:
+                batch_np = pack_batch(batch_np, self.spec.shard_alloc,
+                                      self.spec.n_micro)
+            return shard_batch(batch_np, self.mesh, self.batch_specs)
 
 
 def _check_shard_alloc(shard_alloc, plan: MeshPlan, n_micro: int,
@@ -446,29 +449,36 @@ def _bucketed_grad_fn(spec: TrainSpec, base_loss, buckets):
         leaves, treedef = jax.tree_util.tree_flatten(grads)
         new_leaves = list(leaves)
         new_ef = dict(ef)
-        for bi, (free, idxs, _sizes) in enumerate(buckets):
-            flat = jnp.concatenate(
-                [leaves[i].astype(jnp.float32).reshape(-1) for i in idxs])
-            if fmt != "none":
-                if ef_on:
-                    k = _ef_key(bi)
-                    flat, res = roundtrip_ef(flat, ef[k][0], fmt=fmt,
-                                             tile=tile)
-                    new_ef[k] = res[None]
-                else:
-                    flat = roundtrip(flat, fmt=fmt, tile=tile)
-            if free:
-                flat = jax.lax.psum(flat, free)
-            off = 0
-            for i in idxs:
-                n = new_leaves[i].size
-                new_leaves[i] = flat[off:off + n].reshape(
-                    leaves[i].shape).astype(leaves[i].dtype)
-                off += n
+        with scopes.scope(scopes.GRAD_REDUCE):
+            for bi, (free, idxs, _sizes) in enumerate(buckets):
+                flat = jnp.concatenate(
+                    [leaves[i].astype(jnp.float32).reshape(-1) for i in idxs])
+                if fmt != "none":
+                    if ef_on:
+                        k = _ef_key(bi)
+                        flat, res = roundtrip_ef(flat, ef[k][0], fmt=fmt,
+                                                 tile=tile)
+                        new_ef[k] = res[None]
+                    else:
+                        flat = roundtrip(flat, fmt=fmt, tile=tile)
+                if free:
+                    flat = jax.lax.psum(flat, free)
+                off = 0
+                for i in idxs:
+                    n = new_leaves[i].size
+                    new_leaves[i] = flat[off:off + n].reshape(
+                        leaves[i].shape).astype(leaves[i].dtype)
+                    off += n
         return loss, metrics, jax.tree_util.tree_unflatten(
             treedef, new_leaves), new_ef
 
     return fn
+
+
+def _update(optimizer, grads, opt_state, params):
+    """The optimizer update, under its profiler scope."""
+    with scopes.scope(scopes.OPTIMIZER):
+        return optimizer.update(grads, opt_state, params)
 
 
 def _assemble_train_step(cfg: ModelConfig, production_mesh: Mesh,
@@ -513,7 +523,7 @@ def _assemble_train_step(cfg: ModelConfig, production_mesh: Mesh,
 
     def step_fn(params, opt_state, batch):
         (loss, metrics), grads = grad_fn(params, batch)
-        new_params, new_opt = optimizer.update(grads, opt_state, params)
+        new_params, new_opt = _update(optimizer, grads, opt_state, params)
         return new_params, new_opt, loss, metrics
 
     jit_grad = jax.jit(grad_fn, in_shardings=(param_shardings, batch_sh))
@@ -532,11 +542,11 @@ def _assemble_train_step(cfg: ModelConfig, production_mesh: Mesh,
         # the param specs).
         def async_step_fn(params, opt_state, grad_buf, batch):
             (loss, metrics), grads = grad_fn(params, batch)
-            new_params, new_opt = optimizer.update(grad_buf, opt_state, params)
+            new_params, new_opt = _update(optimizer, grad_buf, opt_state, params)
             return new_params, new_opt, grads, loss, metrics
 
         def flush_fn(params, opt_state, grad_buf):
-            return optimizer.update(grad_buf, opt_state, params)
+            return _update(optimizer, grad_buf, opt_state, params)
 
         jit_async = jax.jit(async_step_fn, in_shardings=(
             param_shardings, opt_sh, param_shardings, batch_sh),
@@ -581,7 +591,7 @@ def _assemble_bucketed(spec: TrainSpec, mesh: Mesh, optimizer, abstract,
 
     def step_fn(params, opt_state, ef, batch):
         (loss, metrics), grads, ef = grad_fn(params, batch, ef)
-        new_params, new_opt = optimizer.update(grads, opt_state, params)
+        new_params, new_opt = _update(optimizer, grads, opt_state, params)
         return new_params, new_opt, ef, loss, metrics
 
     jit_grad = jax.jit(grad_fn, in_shardings=(param_shardings, batch_sh,
@@ -594,11 +604,11 @@ def _assemble_bucketed(spec: TrainSpec, mesh: Mesh, optimizer, abstract,
     if spec.staleness >= 1:
         def async_step_fn(params, opt_state, grad_buf, ef, batch):
             (loss, metrics), grads, ef = grad_fn(params, batch, ef)
-            new_params, new_opt = optimizer.update(grad_buf, opt_state, params)
+            new_params, new_opt = _update(optimizer, grad_buf, opt_state, params)
             return new_params, new_opt, grads, ef, loss, metrics
 
         def flush_fn(params, opt_state, grad_buf):
-            return optimizer.update(grad_buf, opt_state, params)
+            return _update(optimizer, grad_buf, opt_state, params)
 
         jit_async = jax.jit(async_step_fn, in_shardings=(
             param_shardings, opt_sh, param_shardings, ef_sh, batch_sh),
