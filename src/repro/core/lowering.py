@@ -12,8 +12,11 @@ ppermute pipeline.
 ``lower_plan`` translates between the two worlds:
 
 * stage count        -> ``MeshPlan.stage`` (must divide the mesh model axis),
-* layer ranges       -> per-stage *period* ranges, cuts snapped to period
-                        boundaries (periods are the runtime's atomic unit),
+* layer ranges       -> per-stage *period* ranges: the planner's cuts
+                        snapped to period boundaries (periods are the
+                        runtime's atomic unit), then re-cut to the most even
+                        split, since the runtime pads every stage to the
+                        largest share (``even_periods``),
 * ``Plan.n_micro``   -> the runtime's micro-batch count ``M``,
 * per-stage warm-up  -> K_p from ``core.schedule`` (validated against the
                         plan's own ``StagePlan.k_p``),
@@ -47,7 +50,7 @@ from .costmodel import kp_policy, stage_memory
 from .planner import Plan
 from .profiler import Profile
 from .schedule import max_inflight, schedule_orders
-from .simulator import SimResult, simulate
+from .simulator import SimResult, reprice_plan, simulate
 
 
 class LoweringError(RuntimeError):
@@ -64,11 +67,13 @@ class LoweredPlan:
     micro_batch: int                            # samples per micro-batch
     global_batch: int
     n_periods: int                              # real periods in the model
-    stage_periods: tuple[tuple[int, int], ...]  # period range [i, j) per stage
-    stage_layers: tuple[tuple[int, int], ...]   # original table layer ranges
+    stage_periods: tuple[tuple[int, int], ...]  # deployed period range [i, j)
+    stage_layers: tuple[tuple[int, int], ...]   # deployed table layer ranges
     device_groups: tuple[tuple[int, ...], ...]  # edge-cluster ranks (Plan)
     micro_alloc: tuple[tuple[int, ...], ...]    # per-device sample allocation
     warmup: tuple[int, ...]                     # K_p per stage
+    # the planner's Eq. 4 cut snapped to periods, before the even re-cut
+    planner_periods: tuple[tuple[int, int], ...] = ()
 
     @property
     def k_per_stage(self) -> int:
@@ -146,8 +151,8 @@ class LoweredPlan:
 # ---------------------------------------------------------------------------
 
 
-def _snap_to_periods(stage_layers, n_layers: int, pattern_len: int,
-                     n_periods: int) -> tuple[tuple[int, int], ...]:
+def snap_to_periods(stage_layers, n_layers: int, pattern_len: int,
+                    n_periods: int) -> tuple[tuple[int, int], ...]:
     """Snap table-coordinate layer cuts to period boundaries.
 
     Table layout: index 0 = embed, 1..n_layers = real layers, L-1 = head.
@@ -168,6 +173,36 @@ def _snap_to_periods(stage_layers, n_layers: int, pattern_len: int,
         cuts.append(per)
     cuts.append(n_periods)
     return tuple((cuts[p], cuts[p + 1]) for p in range(P))
+
+
+def even_periods(planner_periods, n_periods: int) -> tuple[tuple[int, int], ...]:
+    """The period cut the padded tick scan runs fastest.
+
+    Every stage computes the largest share ``k`` of periods on every tick
+    (``arrange_periods`` pads the others with zero periods), so a step costs
+    ``k`` period slots per tick whatever the split; ``k`` is least,
+    ``ceil(n_periods / P)``, exactly when the shares differ by at most one.
+    The ``n_periods % P`` larger shares go to the stages with the largest
+    shares in ``planner_periods`` (the earlier on a tie), so a cut that is
+    already that even is returned unchanged.
+    """
+    P = len(planner_periods)
+    q, r = divmod(n_periods, P)
+    by_share = sorted(range(P), key=lambda p: (planner_periods[p][0]
+                                               - planner_periods[p][1], p))
+    larger = set(by_share[:r])
+    cuts = [0]
+    for p in range(P):
+        cuts.append(cuts[-1] + q + (p in larger))
+    return tuple(zip(cuts[:-1], cuts[1:]))
+
+
+def period_layers(stage_periods, pattern_len: int,
+                  L: int) -> tuple[tuple[int, int], ...]:
+    """Table layer ranges of a period cut: the first stage also owns the
+    embedding (table layer 0), the last the head (table layer ``L - 1``)."""
+    cuts = [0] + [1 + j * pattern_len for _, j in stage_periods[:-1]] + [L]
+    return tuple(zip(cuts[:-1], cuts[1:]))
 
 
 def lower_plan(plan: Plan, cfg, model_axis: int | None = None) -> LoweredPlan:
@@ -192,13 +227,14 @@ def lower_plan(plan: Plan, cfg, model_axis: int | None = None) -> LoweredPlan:
             f"({len(cfg.pattern)})")
     n_periods = cfg.n_layers // len(cfg.pattern)
 
-    stage_layers = tuple(st.layers for st in plan.stages)
-    for (a, b), (c, _) in zip(stage_layers[:-1], stage_layers[1:]):
+    planner_layers = tuple(st.layers for st in plan.stages)
+    for (a, b), (c, _) in zip(planner_layers[:-1], planner_layers[1:]):
         if b != c:
             raise LoweringError(f"stage layer ranges not contiguous: {b} != {c}")
 
-    stage_periods = _snap_to_periods(stage_layers, cfg.n_layers,
-                                     len(cfg.pattern), n_periods)
+    planner_periods = snap_to_periods(planner_layers, cfg.n_layers,
+                                      len(cfg.pattern), n_periods)
+    stage_periods = even_periods(planner_periods, n_periods)
 
     warmup = tuple(kp_policy(P, p) for p in range(P))
     for p, st in enumerate(plan.stages):
@@ -216,9 +252,11 @@ def lower_plan(plan: Plan, cfg, model_axis: int | None = None) -> LoweredPlan:
         arch=plan.arch, stage=P, n_micro=plan.n_micro,
         micro_batch=plan.micro_batch, global_batch=plan.global_batch,
         n_periods=n_periods, stage_periods=stage_periods,
-        stage_layers=stage_layers,
+        stage_layers=period_layers(stage_periods, len(cfg.pattern),
+                                   cfg.n_layers + 2),
         device_groups=tuple(st.group for st in plan.stages),
-        micro_alloc=tuple(st.alloc for st in plan.stages), warmup=warmup)
+        micro_alloc=tuple(st.alloc for st in plan.stages), warmup=warmup,
+        planner_periods=planner_periods)
 
 
 # ---------------------------------------------------------------------------
@@ -303,6 +341,9 @@ def check_against_simulator(lowered: LoweredPlan, plan: Plan,
                             rel_tol: float = 1e-6) -> SimResult:
     """Assert the lowered schedule agrees with the discrete-event simulator.
 
+    The simulator runs the *deployed* plan: ``plan`` on the lowered cut
+    (``snap_plan``), re-priced on ``profile``.
+
     1. every stage executes exactly M forwards + M backwards,
     2. the simulator's makespan on a unit-cost copy of the plan equals the
        lowered schedule's tick count (two independent implementations of
@@ -310,23 +351,34 @@ def check_against_simulator(lowered: LoweredPlan, plan: Plan,
     3. peak resident activations per stage equal ``min(max(1, K_p), M)`` —
        the O(K_p) 1F1B memory bound — and the simulator's per-device peak
        bytes stay within the Eq. (3) budget the lowering derives,
-    4. the plan's stage latencies are Eq. (8): the max over the group of
-       per-device times priced at the *allocated* sample counts (catches
+    4. ``plan``'s own stage latencies are Eq. (8): the max over the group
+       of per-device times priced at the *allocated* sample counts (catches
        plans whose steps went stale against their allocations),
     5. the simulator's per-device busy times scale with allocated samples —
        ``M * (t_f(d, y_d) + t_b(d, y_d))`` exactly — and never exceed the
        lockstep stage busy time.
-    Returns the (real-cost) simulation for further inspection.
+    Returns the (real-cost) simulation of the deployed plan for further
+    inspection.
     """
     M, P = lowered.n_micro, lowered.stage
-    sim = simulate(plan, profile, policy)
+
+    for p, st in enumerate(s for s in plan.steps if s.kind == "exec"):
+        i, j = st.layers
+        ef = max(profile.t_fwd(d, y, i, j) for d, y in zip(st.group, st.alloc))
+        eb = max(profile.t_bwd(d, y, i, j) for d, y in zip(st.group, st.alloc))
+        assert abs(st.ef - ef) <= rel_tol * max(ef, 1e-12), (p, st.ef, ef)
+        assert abs(st.eb - eb) <= rel_tol * max(eb, 1e-12), (p, st.eb, eb)
+
+    deployed = reprice_plan(snap_plan(plan, lowered, profile.table.L),
+                            profile)
+    sim = simulate(deployed, profile, policy)
 
     ops_per_stage = [0] * P
     for (_, _, p, _) in sim.trace:
         ops_per_stage[p] += 1
     assert ops_per_stage == [2 * M] * P, (ops_per_stage, M)
 
-    unit = simulate(_unitize(plan), profile, policy)
+    unit = simulate(_unitize(deployed), profile, policy)
     ticks = lowered.tick_makespan(policy)
     assert abs(unit.makespan - ticks) <= rel_tol * ticks, \
         (unit.makespan, ticks)
@@ -339,13 +391,8 @@ def check_against_simulator(lowered: LoweredPlan, plan: Plan,
     for d, peak in sim.peak_mem.items():
         assert peak <= bound[d] * (1 + rel_tol), (d, peak, bound[d])
 
-    exec_steps = [s for s in plan.steps if s.kind == "exec"]
-    for p, st in enumerate(exec_steps):
+    for p, st in enumerate(s for s in deployed.steps if s.kind == "exec"):
         i, j = st.layers
-        ef = max(profile.t_fwd(d, y, i, j) for d, y in zip(st.group, st.alloc))
-        eb = max(profile.t_bwd(d, y, i, j) for d, y in zip(st.group, st.alloc))
-        assert abs(st.ef - ef) <= rel_tol * max(ef, 1e-12), (p, st.ef, ef)
-        assert abs(st.eb - eb) <= rel_tol * max(eb, 1e-12), (p, st.eb, eb)
         for d, y in zip(st.group, st.alloc):
             t_dev = M * (profile.t_fwd(d, y, i, j) + profile.t_bwd(d, y, i, j))
             assert abs(sim.device_busy[d] - t_dev) <= \
@@ -383,17 +430,17 @@ def relower(old: LoweredPlan, new_plan: Plan, cfg,
 
 
 def snap_plan(plan: Plan, lowered: LoweredPlan, L: int) -> Plan:
-    """``plan`` with stage layer ranges snapped to what was deployed.
+    """``plan`` with stage layer ranges set to what was deployed.
 
-    Lowering snaps layer cuts to period boundaries; the plan the runtime
-    actually executes therefore owns the *snapped* ranges.  The returned
-    plan (stage ranges and exec-step ranges rewritten; costs kept as the
-    planner's estimates) is what a session should feed back into
-    ``lightweight_replay`` so old-ownership accounting matches reality.
+    Lowering snaps layer cuts to period boundaries and re-cuts them evenly
+    (``even_periods``); the plan the runtime actually executes therefore
+    owns the deployed ranges.  The returned plan (stage ranges and
+    exec-step ranges rewritten; costs kept as the planner's estimates) is
+    what a session should feed back into ``lightweight_replay`` so
+    old-ownership accounting matches reality.
     """
-    plen = (L - 2) // lowered.n_periods
-    cuts = [0] + [1 + j * plen for _, j in lowered.stage_periods[:-1]] + [L]
-    ranges = [(cuts[p], cuts[p + 1]) for p in range(lowered.stage)]
+    ranges = period_layers(lowered.stage_periods,
+                           (L - 2) // lowered.n_periods, L)
     stages = tuple(dataclasses.replace(st, layers=r)
                    for st, r in zip(plan.stages, ranges))
     ex = iter(ranges)

@@ -54,7 +54,8 @@ import numpy as np
 from .allocation import AllocationError, allocate_microbatch
 from .costmodel import Step, allreduce_time, hpp_round_latency, kp_policy
 from .hardware import DeviceProfile
-from .lowering import DIRECT_SOURCE
+from .lowering import (DIRECT_SOURCE, even_periods, period_layers,
+                       snap_to_periods)
 from .planner import Plan, StagePlan, _comm_step, plan_hpp
 from .profiler import Profile
 
@@ -433,25 +434,20 @@ def _stage_capacity(profile: Profile, group, i: int, j: int, mb: int) -> float:
 
 
 def _snap_cuts(cuts: list[int], quantum: int, L: int) -> list[int]:
-    """Snap interior table-layer cuts to period boundaries.
-
-    Mirrors ``lowering._snap_to_periods`` (table layer 1 + r*quantum is the
-    boundary after real-layer period r) so a snapped plan lowers to exactly
-    these cuts.  Kept strictly monotone with >= 1 period per stage.
+    """Interior table-layer cuts as lowering deploys them: snapped to period
+    boundaries, then re-cut to the most even period split, so a plan with
+    these cuts lowers to exactly them and the analytical migration matches
+    what ``migrate_params`` moves.
     """
     n_layers = L - 2                       # embed + real layers + head
     n_per = n_layers // quantum
     P = len(cuts) - 1
     if P > n_per:
         raise AllocationError(f"{P} stages but only {n_per} periods")
-    pers = [0]
-    for p in range(P - 1):
-        r = min(max(cuts[p + 1] - 1, 0), n_layers)
-        per = round(r / quantum)
-        per = max(per, pers[-1] + 1)
-        per = min(per, n_per - (P - 1 - p))
-        pers.append(per)
-    return [0] + [1 + per * quantum for per in pers[1:]] + [L]
+    planner = snap_to_periods(list(zip(cuts[:-1], cuts[1:])), n_layers,
+                              quantum, n_per)
+    periods = even_periods(planner, n_per)
+    return [i for i, _ in period_layers(periods, quantum, L)] + [L]
 
 
 def _capacity_cuts(profile: Profile, groups, mb: int,

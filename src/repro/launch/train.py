@@ -294,7 +294,8 @@ def main(argv=None) -> dict:
                                       **compress_kw)
             lowered = session.lowered
             print(f"asteroid plan: {lowered.stage} stages periods="
-                  f"{lowered.stage_periods} M={lowered.n_micro} "
+                  f"{lowered.stage_periods} (planner "
+                  f"{lowered.planner_periods}) M={lowered.n_micro} "
                   f"K_p={lowered.warmup} predicted latency {plan.latency:.3f}s")
             return {"loss": _run_session(session, cfg, args, events)}
         ts, lowered = plan_to_train_step(plan, prof, cfg, mesh, optimizer=opt,
@@ -302,7 +303,8 @@ def main(argv=None) -> dict:
                                          double_buffer=args.double_buffer,
                                          **compress_kw)
         print(f"asteroid plan: {lowered.stage} stages periods="
-              f"{lowered.stage_periods} M={lowered.n_micro} "
+              f"{lowered.stage_periods} (planner {lowered.planner_periods}) "
+              f"M={lowered.n_micro} "
               f"K_p={lowered.warmup} alloc={lowered.micro_alloc} "
               f"predicted latency {plan.latency:.3f}s")
     else:

@@ -3,21 +3,32 @@ consistency (pure-python; the jax end-to-end path is covered by
 tests/test_distributed.py::test_train_planned_lowering)."""
 
 import dataclasses
+import json
+from pathlib import Path
 
+import hypothesis.strategies as hst
 import numpy as np
 import pytest
+from hypothesis import assume, given, settings
 
+from repro.configs import get_config
 from repro.core.costmodel import kp_policy
-from repro.core.hardware import env_b, env_d
+from repro.core.hardware import env_b, env_c, env_d, env_v5e
 from repro.core.lowering import (LoweredPlan, LoweringError,
-                                 check_against_simulator, lower_micro_alloc,
-                                 lower_plan)
-from repro.core.planner import plan_gpipe, plan_hpp
+                                 check_against_simulator, even_periods,
+                                 lower_micro_alloc, lower_plan, snap_plan)
+from repro.core.planner import Plan, plan_gpipe, plan_hpp
 from repro.core.profiler import LayerTable, Profile
+from repro.core.replay import _plan_from_cuts
 from repro.core.schedule import max_inflight, schedule_orders
-from repro.core.simulator import simulate
+from repro.core.simulator import reprice_plan, simulate
 from repro.data import pack_batch, pack_indices
+from repro.distributed.mesh import MeshPlan
 from repro.models import AttentionConfig, LayerSpec, ModelConfig
+from repro.runtime.pipeline import TrainSpec, slot_counts
+
+PHI3_L8 = (Path(__file__).resolve().parents[1]
+           / "benchmarks/chip/configs/phi3-mini-l8.json")
 
 
 @pytest.fixture(scope="module")
@@ -88,9 +99,12 @@ def test_gpipe_ticks_equal_runtime_scan(setup):
 
 
 def test_memory_bound_tracks_simulator(setup):
+    """The Eq. 3 bound of the lowered cut covers the simulated peaks of the
+    plan as deployed (on that cut, re-priced)."""
     cfg, prof, plan = setup
     low = lower_plan(plan, cfg)
-    sim = simulate(plan, prof)
+    sim = simulate(reprice_plan(snap_plan(plan, low, prof.table.L), prof),
+                   prof)
     bound = low.memory_bound(prof)
     assert set(bound) == set(sim.peak_mem)
     for d in bound:
@@ -252,3 +266,140 @@ def test_heterogeneous_cluster_envs(setup):
             plan = mk()
             low = lower_plan(plan, cfg)
             check_against_simulator(low, plan, prof)
+
+
+# ---------------------------------------------------------------------------
+# The deployed period cut: the most even split of the period stack
+# ---------------------------------------------------------------------------
+
+
+def _small_cfg(n_layers):
+    return ModelConfig(name="p", n_layers=n_layers, d_model=128,
+                       vocab_size=4000, d_ff=512,
+                       attn=AttentionConfig(n_heads=4, n_kv_heads=4,
+                                            head_dim=32),
+                       pattern=(LayerSpec(),))
+
+
+def _plan_on_cuts(prof, groups, cuts):
+    """A plan on the given table cuts and device groups, priced by
+    Algorithm 1 within each stage (global batch 8, micro-batch 2)."""
+    return _plan_from_cuts(Plan("p", (), (), 2, 4, 0.0), prof, groups, cuts,
+                           planner="test")
+
+
+def _computed_slots(cfg, low, model_axis):
+    spec = TrainSpec(cfg=cfg, plan=MeshPlan(pod=1, data=1, stage=low.stage,
+                                            tp=model_axis // low.stage),
+                     n_micro=low.n_micro, stage_periods=low.stage_periods)
+    return slot_counts(spec)
+
+
+@hst.composite
+def planner_cuts(draw):
+    """A planner-shaped plan: any stage count dividing a model axis of at
+    most 8, any contiguous table cut, on a homogeneous v5e host or a
+    heterogeneous edge cluster (the paper's env C)."""
+    n = draw(hst.integers(1, 32))
+    axis = draw(hst.integers(1, 8))
+    P = draw(hst.sampled_from([d for d in range(1, axis + 1)
+                               if axis % d == 0]))
+    hetero = draw(hst.booleans())
+    cluster = env_c() if hetero else env_v5e(axis)
+    assume(P <= n and P <= len(cluster.devices))
+    L = n + 2
+    interior = sorted(draw(hst.sets(hst.integers(1, L - 1), min_size=P - 1,
+                                    max_size=P - 1)))
+    devs = len(cluster.devices)
+    bounds = [p * devs // P for p in range(P + 1)]
+    groups = [tuple(range(bounds[p], bounds[p + 1])) for p in range(P)]
+    return n, axis, cluster, groups, [0] + interior + [L]
+
+
+@settings(max_examples=200, deadline=None)
+@given(planner_cuts())
+def test_lowered_cut_is_most_even(case):
+    """Every lowered cut is contiguous, covers all periods, and has shares
+    that differ by at most one, so the padded scan computes
+    P * ceil(n / P) periods per tick; the simulator cross-check holds on the
+    deployed cut."""
+    n, axis, cluster, groups, cuts = case
+    cfg = _small_cfg(n)
+    prof = Profile.analytic(LayerTable.from_model_config(cfg, seq_len=64),
+                            cluster.sorted_by_memory(), max_batch=8)
+    plan = _plan_on_cuts(prof, groups, cuts)
+    low = lower_plan(plan, cfg, axis)
+    P = low.stage
+    assert low.stage_periods[0][0] == 0 and low.stage_periods[-1][1] == n
+    for (_, b), (c, _) in zip(low.stage_periods[:-1], low.stage_periods[1:]):
+        assert b == c
+    shares = [j - i for i, j in low.stage_periods]
+    assert min(shares) >= 1 and max(shares) - min(shares) <= 1
+    assert low.k_per_stage == -(-n // P)
+    real, computed = _computed_slots(cfg, low, axis)
+    assert real == n * low.n_micro
+    assert computed == P * -(-n // P) * low.forward_ticks
+    # the planner's own cut is kept beside it, and its costs are untouched
+    assert len(low.planner_periods) == P
+    assert [st.layers for st in plan.stages] == [
+        (cuts[p], cuts[p + 1]) for p in range(P)]
+    assert snap_plan(plan, low, prof.table.L).stages[0].layers == \
+        low.stage_layers[0]
+    check_against_simulator(low, plan, prof)
+
+
+@pytest.mark.parametrize("cuts, groups, axis", [
+    ([0, 10], [(0, 1, 2, 3)], 4),                      # P = 1
+    ([0, 3, 5, 7, 10], [(0,), (1,), (2,), (3,)], 4),   # 2|2|2|2
+    ([0, 4, 7, 10], [(0,), (1,), (2,)], 3),            # 3|3|2
+    ([0, 3, 6, 10], [(0,), (1,), (2,)], 3),            # 2|3|3
+])
+def test_even_planner_cut_passes_through(cuts, groups, axis):
+    """An already-even planner cut, and any single-stage plan, is deployed
+    unchanged."""
+    cfg = _small_cfg(cuts[-1] - 2)
+    prof = Profile.analytic(LayerTable.from_model_config(cfg, seq_len=64),
+                            env_v5e(axis), max_batch=8)
+    plan = _plan_on_cuts(prof, groups, cuts)
+    low = lower_plan(plan, cfg, axis)
+    assert low.stage_periods == low.planner_periods
+    assert low.stage_layers == tuple(st.layers for st in plan.stages)
+    assert snap_plan(plan, low, prof.table.L) == plan
+
+
+def test_even_periods_gives_extras_to_the_largest_planner_shares():
+    assert even_periods(((0, 3), (3, 5), (5, 7), (7, 8)), 8) == \
+        ((0, 2), (2, 4), (4, 6), (6, 8))
+    # 1|3|3 over 7: the one larger share goes to the first largest stage
+    assert even_periods(((0, 1), (1, 4), (4, 7)), 7) == \
+        ((0, 2), (2, 5), (5, 7))
+    # 3|3|3|1 already pads to 3, but is not the most even: 3|3|2|2
+    assert even_periods(((0, 3), (3, 6), (6, 9), (9, 10)), 10) == \
+        ((0, 3), (3, 6), (6, 8), (8, 10))
+    assert even_periods(((0, 5),), 5) == ((0, 5),)
+
+
+def test_four_chip_job_lowers_onto_the_even_cut():
+    """The calls of the four-chip benchmark job (analytic v5e host of four,
+    stage counts dividing the model axis, batch 8 in micro-batches of 1) on
+    phi3-mini at 8 layers: the planner's Eq. 4 cut 3|2|2|1 is deployed as
+    2|2|2|2, and the padded scan computes 88 slots for 64 real ones."""
+    conf = json.loads(PHI3_L8.read_text())
+    model = dict(conf["model"])
+    base = get_config(conf["arch"])
+    cfg = base.replace(attn=dataclasses.replace(base.attn,
+                                                **model.pop("attn")), **model)
+    table = LayerTable.from_model_config(cfg, 2048)
+    prof = Profile.analytic(table, env_v5e(4).sorted_by_memory(),
+                            max_batch=8)
+    plan = plan_hpp(prof, 8, 1, arch=cfg.name, allowed_stages={1, 2, 4},
+                    intra_opt="auto", staleness=0, compress=None)
+    low = lower_plan(plan, cfg, 4)
+    assert low.planner_periods == ((0, 3), (3, 5), (5, 7), (7, 8))
+    assert low.stage_periods == ((0, 2), (2, 4), (4, 6), (6, 8))
+    assert _computed_slots(cfg, low, 4) == (64, 88)
+    # the Plan keeps the planner's Eq. 4 cut and latency
+    assert [st.layers for st in plan.stages] == [(0, 4), (4, 6), (6, 8),
+                                                 (8, 10)]
+    assert plan.latency == pytest.approx(11.635, rel=1e-3)
+    check_against_simulator(low, plan, prof)

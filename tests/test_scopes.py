@@ -102,7 +102,8 @@ def spec(n_layers, stages, n_micro, stage_periods=None, double_buffer=False):
 
 
 def test_slot_counts_of_the_four_chip_plan():
-    # stages (3|2|2|1) padded to 3 slots, M + P - 1 = 11 ticks
+    # the planner's Eq. 4 cut (3|2|2|1), padded to 3 slots, M + P - 1 = 11
+    # ticks; lowering deploys 2|2|2|2 (test_lowering pins its 64/88)
     s = spec(8, 4, 8, ((0, 3), (3, 5), (5, 7), (7, 8)))
     assert slot_counts(s) == (64, 132)
 
