@@ -4,7 +4,9 @@ A cell is found by its name in ``BENCHMARK.json``; its configuration,
 traffic mix, limits and per-layer metrics are files of their own under
 this directory, found by the names given there:
 
-* ``configs/<config>.json``: the model as run, its source and cut;
+* ``configs/<config>.json``: the model as run, its source and cut; its
+  ``reference`` names the model family, whose plain reference is
+  ``reference/<family>.py`` and whose FLOP count is ``flops/<family>.py``;
 * ``traffic/<traffic>.json``: the job (``jobs/<job>.py``) and its sizes;
 * ``limits/<cell>.json``: the limit of each number ``correct`` compares;
 * ``metrics/<metric>.py``: the reader of one per-layer metric;

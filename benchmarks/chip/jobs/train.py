@@ -17,6 +17,15 @@ dispatches steps back to back for ``--seconds``, keeping at most
 ``IN_FLIGHT`` steps queued so that the host never runs ahead of the chip by
 more than that; it ends when the last step dispatched has finished.
 
+A traced run then maps the step's compiled text to program scopes
+(``scopes.py``) and traces ``TRACED_STEPS`` more steps through the same
+call, keeping their ``metrics`` output.  It stops, with no result, where
+the text names no scope or the scopes cover under ``scopes.SCOPED_FLOOR``
+of a chip's busy time.  The per-layer readers get the device time of each
+scope, what the program counts about the built step
+(``program_counters``), the mean of each step metric, the model's sizes and
+its family's FLOP counts (``flops/``).
+
 Once the window has closed, peak memory has been read and the program's
 state is freed, the plain reference (``reference/``) trains the same
 weights on the same batches for the same steps, a few sequences at a
@@ -37,7 +46,7 @@ import numpy as np
 from jax.profiler import TraceAnnotation
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from benchmarks.chip import flops, trace
+from benchmarks.chip import flops, scopes, trace
 from benchmarks.chip.reference import common as ref
 from benchmarks.chip.stream import BigramStream
 
@@ -173,19 +182,23 @@ class Program:
         after a dispatch, at most ``IN_FLIGHT`` queued; then wait for the
         last.  A host span named ``span`` opens once the first step is
         dispatched (the chip is busy from then on) and closes after the
-        wait.  Returns ``(state, steps, losses)``."""
+        wait; under it the steps' ``metrics`` outputs are kept.  Returns
+        ``(state, steps, losses, metrics)``."""
         params, opt_state = state
         gb = self.traffic["global_batch"]
         queue, losses, step = collections.deque(), [], first_step
+        kept = []
         with contextlib.ExitStack() as window:
             while True:
                 with TraceAnnotation("bench.make_batch"):
                     batch = self.ts.shard_batch(ds.batch(step, gb))
                 with TraceAnnotation(trace.DISPATCH_SPAN):
-                    params, opt_state, loss, _ = self.step_fn(
+                    params, opt_state, loss, metrics = self.step_fn(
                         params, opt_state, batch)
                 if span and step == first_step:
                     window.enter_context(TraceAnnotation(span))
+                if span:
+                    kept.append(metrics)
                 losses.append(loss)
                 queue.append(loss)
                 step += 1
@@ -196,7 +209,23 @@ class Program:
                     break
             with TraceAnnotation("bench.wait_last"):
                 jax.block_until_ready((params, opt_state))
-        return (params, opt_state), step - first_step, losses
+        return (params, opt_state), step - first_step, losses, kept
+
+
+def program_counters(spec) -> dict:
+    """What the program counts about the built step: its period slots,
+    real and computed (``pipeline.slot_counts``)."""
+    from repro.runtime.pipeline import slot_counts
+
+    real, computed = slot_counts(spec)
+    return {"slots_real": real, "slots_computed": computed}
+
+
+def mean_metrics(kept: list) -> dict:
+    """The mean over steps of each scalar of the steps' ``metrics``."""
+    kept = jax.device_get(kept)
+    return {k: float(np.mean([m[k] for m in kept])) for k in kept[0]
+            if np.ndim(kept[0][k]) == 0} if kept else {}
 
 
 def _layer_rows(stage_periods, n_periods: int) -> list:
@@ -344,25 +373,44 @@ def run(cell, devs, log, seed: int, seconds: float, traced: bool,
     t0 = time.perf_counter()
     setup_s = t0 - t_start
     deadline = t0 + seconds
-    state, n_steps, window_losses = prog.drive(
+    state, n_steps, window_losses, _ = prog.drive(
         state, ds, CHECKED_STEPS, lambda n: time.perf_counter() >= deadline)
     window_s = time.perf_counter() - t0
     tok_s = n_steps * tokens / window_s
     log(f"window: {n_steps} steps of {tokens} tokens in {window_s:.6f} s, "
         f"{tok_s:.3f} tokens/s; set-up {setup_s:.6f} s")
 
-    summary = None
+    gb, seq = cell.traffic["global_batch"], cell.traffic["seq"]
+    summary, step_metrics = None, {}
     if traced:
+        t_map = time.perf_counter()
+        smap = scopes.scope_map(scopes.compiled_text(
+            prog.step_fn, *state, prog.ts.shard_batch(ds.batch(0, gb))))
+        named = sorted({v for v in smap.scopes.values() if v})
+        log(f"scope map: module {smap.module}, scopes {named}, "
+            f"{time.perf_counter() - t_map:.3f} s")
+        if not named:
+            raise SystemExit("the step's compiled text names no program "
+                             "scope: no per-layer metric could be read")
+
         def traced_window():
             return prog.drive(state, ds, CHECKED_STEPS + n_steps,
                               lambda n: n >= TRACED_STEPS,
                               span=trace.WINDOW_SPAN)
 
-        (state, _, more), summary = trace.traced(traced_window)
+        (state, _, more, kept), summary = trace.traced(traced_window, smap)
         window_losses += more
+        step_metrics = mean_metrics(kept)
+        shares = summary.scoped_shares()
         log(f"trace: {TRACED_STEPS} steps, window "
             f"{summary.window_ns / 1e9:.6f} s, busy {summary.busy_s:.6f} s "
-            f"per chip")
+            f"per chip, scoped " + " ".join(f"{100 * x:.2f}%" for x in shares)
+            + f" of busy; step metrics {step_metrics}")
+        if min(shares) < scopes.SCOPED_FLOOR:
+            raise SystemExit(
+                f"the program scopes cover {100 * min(shares):.2f}% of a "
+                f"chip's busy time, under {100 * scopes.SCOPED_FLOOR:.0f}%: "
+                f"the map and the trace do not match")
 
     window_losses = [float(x) for x in window_losses]
     peak = max((d.memory_stats() or {}).get("peak_bytes_in_use", 0)
@@ -374,13 +422,13 @@ def run(cell, devs, log, seed: int, seconds: float, traced: bool,
     failed = sum(not math.isfinite(x)
                  for x in readings.losses + window_losses)
     plan_latency = prog.plan.latency
+    counters = program_counters(prog.ts.spec)
     family, model = cell.config["reference"], cell.config["model"]
     del state, prog
 
     t_ref = time.perf_counter()
     want = Reference(cell, devs).readings(
-        seed, [ds.batch(s, cell.traffic["global_batch"])
-               for s in range(CHECKED_STEPS)])
+        seed, [ds.batch(s, gb) for s in range(CHECKED_STEPS)])
     log(f"reference: {time.perf_counter() - t_ref:.3f} s; losses: program "
         f"{readings.losses} reference {want.losses}")
     return {
@@ -394,7 +442,12 @@ def run(cell, devs, log, seed: int, seconds: float, traced: bool,
             "tok_s": tok_s, "chips": len(devs),
             "step_s": window_s / n_steps, "plan_latency_s": plan_latency,
             "traced_steps": TRACED_STEPS if traced else 0,
-            "flops_per_token": flops.train_flops_per_token(
-                family, model, cell.traffic["seq"]),
+            "flops_per_token": flops.train_flops_per_token(family, model,
+                                                           seq),
+            "scope_s": summary.scope_s(TRACED_STEPS) if summary else {},
+            "counters": counters, "step_metrics": step_metrics,
+            "model": model, "family": family, "seq": seq,
+            "global_batch": gb,
+            "scope_work": flops.scope_work(family, model, seq),
         },
     }

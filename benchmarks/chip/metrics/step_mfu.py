@@ -1,6 +1,6 @@
 """Model FLOP utilisation of the whole train step, in %.
 
-Model FLOPs per token (``flops.py``: forward and backward, no remat
+Model FLOPs per token (``flops/``: forward and backward, no remat
 recompute) times tokens per second, over the chips' summed bf16 peak.
 """
 

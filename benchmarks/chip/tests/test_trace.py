@@ -1,14 +1,22 @@
-"""The trace reduction, on interval arithmetic and on a small trace
-recorded on a TPU v5e (one chip: five 91 us matmul fusions, each after
-about 4 ms of host work inside ``bench.make_batch``)."""
+"""The trace reduction, on interval arithmetic and on two small traces
+recorded on a TPU v5e: five 91 us matmul fusions on one chip, each after
+about 4 ms of host work inside ``bench.make_batch``; and three steps of the
+smoke-size phi3 train step on one chip, with the compiled HLO text of that
+step."""
 
+import dataclasses
+import gzip
 from pathlib import Path
 
 import pytest
 
-from benchmarks.chip import trace
+from benchmarks.chip import scopes, trace
+from repro import scopes as program_scopes
 
-RECORDED = Path(__file__).parent / "data" / "one_chip_v5e.xplane.pb"
+DATA = Path(__file__).parent / "data"
+RECORDED = DATA / "one_chip_v5e.xplane.pb"
+SCOPED = DATA / "scoped_smoke_v5e.xplane.pb.gz"
+SCOPED_HLO = DATA / "scoped_smoke_v5e.hlo.txt.gz"
 
 
 def test_union_merges_overlaps():
@@ -99,3 +107,65 @@ def test_recorded_breakdown(recorded):
     assert len(gaps) == 10
     assert gaps[0][0] == "bench.make_batch"
     assert 3e-3 < gaps[0][1] < 6e-3
+
+
+@pytest.fixture(scope="module")
+def scoped(tmp_path_factory):
+    """The smoke step's trace reduced without and with its scope map."""
+    path = tmp_path_factory.mktemp("trace") / "scoped.xplane.pb"
+    with gzip.open(SCOPED) as f:
+        path.write_bytes(f.read())
+    with gzip.open(SCOPED_HLO, "rt") as f:
+        smap = scopes.scope_map(f.read())
+    return smap, trace.reduce(str(path)), trace.reduce(str(path), smap)
+
+
+def test_scope_map_changes_no_other_number(scoped):
+    """Window, clock shift and leaf ops are those of the reduction without
+    the map; the numbers the metrics read stay as they were before the
+    scopes were added to it, to the bit."""
+    _, plain, mapped = scoped
+    assert dataclasses.replace(mapped, chips=[]) == dataclasses.replace(
+        plain, chips=[])
+    assert [dataclasses.replace(c, scope_ns={}) for c in mapped.chips] \
+        == plain.chips
+    assert mapped.window_ns == 9250399.0
+    assert [c.busy_ns for c in mapped.chips] == [3200323.0]
+    assert [c.exposed_collective_ns for c in mapped.chips] == [409.0]
+    assert mapped.idle_share() == 0.6540340584227773
+
+
+def test_recorded_time_by_scope(scoped):
+    """Each scope's union of the leaf ops whose innermost scope it is, over
+    the step's module: on one chip the compiler drops the gradient psums
+    over size-1 axes, and there is no last-stage redistribution."""
+    smap, _, mapped = scoped
+    assert smap.module == "jit_step_fn"
+    assert {s for s in smap.scopes.values() if s} == set(
+        program_scopes.ALL) - {program_scopes.REDISTRIBUTE,
+                               program_scopes.GRAD_REDUCE}
+    (chip,) = mapped.chips
+    assert chip.scope_ns == {
+        "pipeline": 118983.0, "attention": 2474388.0, "stage": 137776.0,
+        "mlp": 290335.0, "head_ce": 35375.0, "optimizer": 30451.0,
+        "boundary": 409.0, "embed": 11299.0}
+    # all but a few copies XLA adds carry a scope
+    assert 0.9 * chip.busy_ns <= sum(chip.scope_ns.values()) <= chip.busy_ns
+    assert mapped.scoped_shares() == [sum(chip.scope_ns.values())
+                                      / chip.busy_ns]
+    assert mapped.scoped_shares()[0] >= scopes.SCOPED_FLOOR
+    assert scoped[1].scoped_shares() == [0.0]
+    assert mapped.scope_s(3)["attention"] == [2474388.0 / 1e9 / 3]
+
+
+def test_recorded_gaps_name_the_program_span(scoped):
+    """The program's host span around ``TrainStep.shard_batch`` is kept
+    beside the benchmark's, so a gap under it is named by it."""
+    _, _, mapped = scoped
+    names = {sp[2] for sp in mapped.host_spans}
+    assert program_scopes.SHARD_BATCH_SPAN in names
+    assert trace.DISPATCH_SPAN in names
+    s, e, _ = next(sp for sp in mapped.host_spans
+                   if sp[2] == program_scopes.SHARD_BATCH_SPAN)
+    assert mapped.host_activity((s + e) / 2) \
+        == program_scopes.SHARD_BATCH_SPAN

@@ -10,7 +10,12 @@ chip, over the traced window (the host span ``bench.trace_window``):
 * exposed collective time: the part of the collective ops' intervals in
   which no other op of that chip runs;
 * op totals by name, and the idle gaps between busy intervals, each named
-  by the innermost ``bench.*`` host span that covers its middle.
+  by the innermost host span that covers its middle: the benchmark's
+  ``bench.*`` or the program's ``asteroid.*`` (``repro.scopes``);
+* given the step's scope map (``scopes.scope_map``), the device time of
+  each program scope: the union of the intervals of the leaf ops of the
+  step's module whose innermost ``asteroid/*`` scope it is.  A scope with
+  children (``pipeline``, ``stage``) so gets its self time.
 
 The device's clock in the trace can sit a millisecond or more off the
 host's.  Each chip's events are shifted so that its first program starts
@@ -21,6 +26,7 @@ then, and closes when the host has waited for the last step.
 
 from __future__ import annotations
 
+import bisect
 import dataclasses
 import glob
 import os
@@ -29,6 +35,7 @@ import tempfile
 
 WINDOW_SPAN = "bench.trace_window"
 DISPATCH_SPAN = "bench.dispatch"
+HOST_SPANS = ("bench.", "asteroid.")
 DEVICE_PLANE = "/device:TPU:"
 OPS_LINE = "XLA Ops"
 MODULES_LINE = "XLA Modules"
@@ -36,10 +43,10 @@ COLLECTIVES = ("all-reduce", "all-gather", "reduce-scatter", "all-to-all",
                "collective-permute", "send", "recv")
 
 
-def traced(fn, keep_as: str | None = None):
-    """Run ``fn()`` under the profiler.  Returns ``(fn(), Summary)``; the
-    trace file is deleted after reading, unless ``keep_as`` names a path to
-    copy it to."""
+def traced(fn, smap=None):
+    """Run ``fn()`` under the profiler.  Returns ``(fn(), Summary)``, with
+    the time by scope of ``smap`` (a ``scopes.ScopeMap``) where given; the
+    trace file is deleted after reading."""
     import jax
 
     tmp = tempfile.mkdtemp(prefix="chipbench-trace-")
@@ -51,9 +58,7 @@ def traced(fn, keep_as: str | None = None):
             jax.profiler.stop_trace()
         (path,) = glob.glob(os.path.join(tmp, "**", "*.xplane.pb"),
                             recursive=True)
-        if keep_as:
-            shutil.copyfile(path, keep_as)
-        return out, reduce(path)
+        return out, reduce(path, smap)
     finally:
         shutil.rmtree(tmp, ignore_errors=True)
 
@@ -128,13 +133,15 @@ class Chip:
     exposed_collective_ns: float
     op_ns: dict                   # op name -> summed duration in the window
     gaps: list                    # (start, end) idle intervals in the window
+    # program scope -> union of its ops' intervals in the window
+    scope_ns: dict = dataclasses.field(default_factory=dict)
 
 
 @dataclasses.dataclass
 class Summary:
     window_ns: float
     chips: list
-    host_spans: list              # (start, end, name) of bench.* spans
+    host_spans: list              # (start, end, name) of HOST_SPANS
 
     @property
     def busy_s(self) -> float:
@@ -144,6 +151,17 @@ class Summary:
     def idle_share(self) -> float:
         """Largest idle share of the window over the chips."""
         return max(1.0 - c.busy_ns / self.window_ns for c in self.chips)
+
+    def scoped_shares(self) -> list:
+        """Per chip, the device time of its scopes over its busy time."""
+        return [sum(c.scope_ns.values()) / max(c.busy_ns, 1.0)
+                for c in self.chips]
+
+    def scope_s(self, steps: int) -> dict:
+        """Seconds per step of each program scope, one entry per chip."""
+        names = sorted({k for c in self.chips for k in c.scope_ns})
+        return {k: [c.scope_ns.get(k, 0.0) / 1e9 / steps for c in self.chips]
+                for k in names}
 
     def top_ops(self, n: int = 10) -> list:
         tot: dict = {}
@@ -160,7 +178,7 @@ class Summary:
                 for d, s, e in gaps]
 
     def host_activity(self, t: float) -> str:
-        """The innermost ``bench.*`` span that covers time ``t``."""
+        """The innermost host span that covers time ``t``."""
         best = None
         for s, e, name in self.host_spans:
             if s <= t <= e and (best is None or e - s < best[1] - best[0]):
@@ -168,24 +186,33 @@ class Summary:
         return best[2] if best else "outside any bench span"
 
 
-def chip_summary(name: str, events, window) -> Chip:
+def chip_summary(name: str, events, window, smap=None, runs=()) -> Chip:
     """One chip's numbers from its ``(start, end, op name)`` events, on the
-    host's clock, over ``window = (start, end)``."""
+    host's clock, over ``window = (start, end)``; with the step's scope map
+    and the ``(start, end)`` runs of its module, its time by scope."""
     w0, w1 = window
-    ops, comm, op_ns = [], [], {}
+    runs = union(runs)
+    run_starts = [s for s, _ in runs]
+    ops, comm, op_ns, by_scope = [], [], {}, {}
     for s, e, op in leaves(events):
         s, e = max(s, w0), min(e, w1)
         if e <= s:
             continue
         op_ns[op] = op_ns.get(op, 0.0) + (e - s)
         (comm if is_collective(op) else ops).append((s, e))
+        sc = smap.scopes.get(op) if smap else None
+        if sc:
+            i = bisect.bisect_right(run_starts, s) - 1
+            if i >= 0 and s < runs[i][1]:
+                by_scope.setdefault(sc, []).append((s, e))
     busy = union(ops + comm)
     exposed = subtract(union(comm), union(ops))
     return Chip(name, total(busy), total(exposed), op_ns,
-                subtract([(w0, w1)], busy))
+                subtract([(w0, w1)], busy),
+                {k: total(union(v)) for k, v in by_scope.items()})
 
 
-def reduce(path: str) -> Summary:
+def reduce(path: str, smap=None) -> Summary:
     from jax.profiler import ProfileData
 
     pd = ProfileData.from_file(path)
@@ -195,7 +222,7 @@ def reduce(path: str) -> Summary:
             continue
         for line in plane.lines:
             for ev in line.events:
-                if ev.name.startswith("bench."):
+                if ev.name.startswith(HOST_SPANS):
                     spans.append((ev.start_ns, ev.end_ns, ev.name))
     windows = [(s, e) for s, e, n in spans if n == WINDOW_SPAN]
     if len(windows) != 1:
@@ -209,12 +236,14 @@ def reduce(path: str) -> Summary:
         if not plane.name.startswith(DEVICE_PLANE):
             continue
         lines = {line.name: list(line.events) for line in plane.lines}
-        first = min((ev.start_ns for ev in lines.get(MODULES_LINE, [])),
-                    default=dispatched)
+        modules = lines.get(MODULES_LINE, [])
+        first = min((ev.start_ns for ev in modules), default=dispatched)
         shift = dispatched - first
         events = [(ev.start_ns + shift, ev.end_ns + shift, op_name(ev.name))
                   for ev in lines.get(OPS_LINE, [])]
-        chips.append(chip_summary(plane.name, events, (w0, w1)))
+        runs = [(ev.start_ns + shift, ev.end_ns + shift) for ev in modules
+                if smap and ev.name.split("(", 1)[0] == smap.module]
+        chips.append(chip_summary(plane.name, events, (w0, w1), smap, runs))
     if not chips:
         raise ValueError(f"no {DEVICE_PLANE}* plane in {path}")
     return Summary(float(w1 - w0), chips, spans)
