@@ -9,14 +9,15 @@ from __future__ import annotations
 
 from repro.models import ModelConfig
 
-from . import (deepseek_7b, deepseek_v3, gemma2_2b, gemma_2b, internvl2_2b,
-               jamba_1_5_large, musicgen_large, phi3_5_moe_42b, phi3_mini,
-               rwkv6_7b)
+from . import (deepseek_7b, deepseek_v2_lite, deepseek_v3, gemma2_2b, gemma_2b,
+               internvl2_2b, jamba_1_5_large, musicgen_large, phi3_5_moe_42b,
+               phi3_mini, rwkv6_7b)
 from .common import smoke_reduce
 
 _MODULES = (
     phi3_5_moe_42b, gemma_2b, rwkv6_7b, jamba_1_5_large, phi3_mini,
     musicgen_large, deepseek_v3, internvl2_2b, deepseek_7b, gemma2_2b,
+    deepseek_v2_lite,
 )
 
 ARCH_IDS: tuple[str, ...] = tuple(m.ARCH_ID for m in _MODULES)
@@ -31,6 +32,7 @@ FAMILY = {
     "phi3-mini-3.8b": "dense",
     "musicgen-large": "audio",
     "deepseek-v3-671b": "moe",
+    "deepseek-v2-lite-16b": "moe",
     "internvl2-2b": "vlm",
     "deepseek-7b": "dense",
     "gemma2-2b": "dense",

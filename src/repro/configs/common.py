@@ -9,7 +9,8 @@ from repro.models import (AttentionConfig, LayerSpec, MambaConfig, MLAConfig,
 
 
 def smoke_reduce(cfg: ModelConfig) -> ModelConfig:
-    """Reduced same-family variant: <=2 periods, d_model<=512, <=4 experts.
+    """Reduced same-family variant: <=2 periods, d_model<=512, <=4 experts,
+    at most one leading dense layer.
 
     Keeps the pattern (so hybrid/alternating structure is exercised) while
     shrinking every dimension for a CPU-speed forward/train step.
@@ -30,8 +31,9 @@ def smoke_reduce(cfg: ModelConfig) -> ModelConfig:
         n_kv = max(1, min(a.n_kv_heads, n_heads * a.n_kv_heads // a.n_heads)) or 1
         mla = None
         if a.mla is not None:
-            mla = MLAConfig(q_lora_rank=64, kv_lora_rank=32, qk_nope_dim=32,
-                            qk_rope_dim=16, v_head_dim=32)
+            mla = MLAConfig(q_lora_rank=None if a.mla.q_lora_rank is None else 64,
+                            kv_lora_rank=32, qk_nope_dim=32, qk_rope_dim=16,
+                            v_head_dim=32)
         kw["attn"] = dataclasses.replace(
             a, n_heads=n_heads, n_kv_heads=max(n_kv, 1), head_dim=64,
             window=None if a.window is None else 64,
@@ -42,7 +44,10 @@ def smoke_reduce(cfg: ModelConfig) -> ModelConfig:
             for s in cfg.pattern)
     if cfg.moe is not None:
         kw["moe"] = dataclasses.replace(
-            cfg.moe, n_experts=4, top_k=min(cfg.moe.top_k, 2), d_ff=256)
+            cfg.moe, n_experts=4, n_routed=None, first_expert=0,
+            top_k=min(cfg.moe.top_k, 2), d_ff=256)
+    if cfg.first_k_dense:
+        kw["first_k_dense"] = 1
     if cfg.mamba is not None:
         kw["mamba"] = dataclasses.replace(cfg.mamba, d_state=8, chunk=32)
     if cfg.rwkv is not None:

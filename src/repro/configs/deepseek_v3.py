@@ -1,11 +1,10 @@
 """DeepSeek-V3 671B [arXiv:2412.19437].
 
 61L, d_model=7168, 128 heads with MLA (q_lora=1536, kv_lora=512,
-qk_nope=128, qk_rope=64, v=128), MoE: 1 shared + 256 routed experts top-8
+qk_nope=128, qk_rope=64, v=128).  The first 3 layers are dense (SwiGLU of
+18432, ``first_k_dense_replace`` 3), so ``n_layers`` counts the 58 MoE
+layers of the period stack.  MoE: 1 shared + 256 routed experts top-8
 (sigmoid scores), expert d_ff=2048, vocab=129280, MTP depth 1.
-
-Simplification noted in DESIGN.md: the real model's first 3 layers are
-dense; here every layer is MoE so the body stays a uniform scan.
 """
 
 from repro.models import (AttentionConfig, LayerSpec, MLAConfig, ModelConfig,
@@ -17,10 +16,11 @@ ARCH_ID = "deepseek-v3-671b"
 def config() -> ModelConfig:
     return ModelConfig(
         name=ARCH_ID,
-        n_layers=61,
+        n_layers=58,
+        first_k_dense=3,
         d_model=7168,
         vocab_size=129280,
-        d_ff=2048,
+        d_ff=18432,
         attn=AttentionConfig(
             n_heads=128, n_kv_heads=128, head_dim=128, rope_theta=10000.0,
             mla=MLAConfig(q_lora_rank=1536, kv_lora_rank=512, qk_nope_dim=128,

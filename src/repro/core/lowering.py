@@ -1,8 +1,9 @@
 """Lower an Asteroid ``Plan`` (Algorithm 2 output) into the pipeline runtime.
 
 The planner reasons about an edge cluster in *layer-table* coordinates:
-stages are layer ranges ``[i, j)`` over ``embed + n_layers + head`` pseudo
-layers, device groups are ranks into the profiled cluster, and micro-batch
+stages are layer ranges ``[i, j)`` over ``embed + first_k_dense +
+n_layers + head`` pseudo layers (the leading dense layers always go with the
+first stage), device groups are ranks into the profiled cluster, and micro-batch
 allocations are per-device sample counts.  The shard_map runtime
 (``repro.runtime``) executes in *mesh* coordinates: a refined
 ``(pod, data, stage, tp)`` mesh whose ``stage`` axis slices the stacked
@@ -74,6 +75,7 @@ class LoweredPlan:
     warmup: tuple[int, ...]                     # K_p per stage
     # the planner's Eq. 4 cut snapped to periods, before the even re-cut
     planner_periods: tuple[tuple[int, int], ...] = ()
+    lead: int = 0                               # dense layers ahead of the stack
 
     @property
     def k_per_stage(self) -> int:
@@ -152,12 +154,13 @@ class LoweredPlan:
 
 
 def snap_to_periods(stage_layers, n_layers: int, pattern_len: int,
-                    n_periods: int) -> tuple[tuple[int, int], ...]:
+                    n_periods: int, lead: int = 0) -> tuple[tuple[int, int], ...]:
     """Snap table-coordinate layer cuts to period boundaries.
 
-    Table layout: index 0 = embed, 1..n_layers = real layers, L-1 = head.
-    Interior cuts land on the nearest period boundary, kept strictly
-    monotone so every stage owns >= 1 period.
+    Table layout: index 0 = embed, 1..lead = leading dense layers, then the
+    ``n_layers`` layers of the period stack, L-1 = head.  Interior cuts land
+    on the nearest period boundary, kept strictly monotone so every stage
+    owns >= 1 period.
     """
     P = len(stage_layers)
     if P > n_periods:
@@ -165,7 +168,7 @@ def snap_to_periods(stage_layers, n_layers: int, pattern_len: int,
             f"plan has {P} stages but the model only has {n_periods} periods")
     cuts = [0]
     for s, (i, j) in enumerate(stage_layers[:-1]):
-        r = min(max(j - 1, 0), n_layers)           # cut in real-layer coords
+        r = min(max(j - 1 - lead, 0), n_layers)    # cut in stack-layer coords
         per = round(r / pattern_len)
         # strictly monotone, leaving >= 1 period for each remaining stage
         per = max(per, cuts[-1] + 1)
@@ -198,10 +201,11 @@ def even_periods(planner_periods, n_periods: int) -> tuple[tuple[int, int], ...]
 
 
 def period_layers(stage_periods, pattern_len: int,
-                  L: int) -> tuple[tuple[int, int], ...]:
+                  L: int, lead: int = 0) -> tuple[tuple[int, int], ...]:
     """Table layer ranges of a period cut: the first stage also owns the
-    embedding (table layer 0), the last the head (table layer ``L - 1``)."""
-    cuts = [0] + [1 + j * pattern_len for _, j in stage_periods[:-1]] + [L]
+    embedding (table layer 0) and the ``lead`` dense layers after it, the
+    last the head (table layer ``L - 1``)."""
+    cuts = [0] + [1 + lead + j * pattern_len for _, j in stage_periods[:-1]] + [L]
     return tuple(zip(cuts[:-1], cuts[1:]))
 
 
@@ -232,8 +236,9 @@ def lower_plan(plan: Plan, cfg, model_axis: int | None = None) -> LoweredPlan:
         if b != c:
             raise LoweringError(f"stage layer ranges not contiguous: {b} != {c}")
 
+    lead = cfg.first_k_dense
     planner_periods = snap_to_periods(planner_layers, cfg.n_layers,
-                                      len(cfg.pattern), n_periods)
+                                      len(cfg.pattern), n_periods, lead)
     stage_periods = even_periods(planner_periods, n_periods)
 
     warmup = tuple(kp_policy(P, p) for p in range(P))
@@ -253,10 +258,10 @@ def lower_plan(plan: Plan, cfg, model_axis: int | None = None) -> LoweredPlan:
         micro_batch=plan.micro_batch, global_batch=plan.global_batch,
         n_periods=n_periods, stage_periods=stage_periods,
         stage_layers=period_layers(stage_periods, len(cfg.pattern),
-                                   cfg.n_layers + 2),
+                                   cfg.n_layers + lead + 2, lead),
         device_groups=tuple(st.group for st in plan.stages),
         micro_alloc=tuple(st.alloc for st in plan.stages), warmup=warmup,
-        planner_periods=planner_periods)
+        planner_periods=planner_periods, lead=lead)
 
 
 # ---------------------------------------------------------------------------
@@ -440,7 +445,8 @@ def snap_plan(plan: Plan, lowered: LoweredPlan, L: int) -> Plan:
     old-ownership accounting matches reality.
     """
     ranges = period_layers(lowered.stage_periods,
-                           (L - 2) // lowered.n_periods, L)
+                           (L - 2 - lowered.lead) // lowered.n_periods, L,
+                           lowered.lead)
     stages = tuple(dataclasses.replace(st, layers=r)
                    for st, r in zip(plan.stages, ranges))
     ex = iter(ranges)

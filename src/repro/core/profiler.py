@@ -90,13 +90,19 @@ class LayerTable:
     def from_model_config(cfg, seq_len: int) -> "LayerTable":
         """Analytic table for a transformer ModelConfig (per-sample costs).
 
-        One entry per LayerSpec instance plus embed/head pseudo-layers.
+        One entry per LayerSpec instance plus embed/head pseudo-layers; the
+        leading dense layers (``cfg.first_k_dense``) right after ``embed``,
+        so that the planner charges them to the first stage.
         """
+        from repro.models.config import DENSE_LAYER
+
         d, S = cfg.d_model, seq_len
         layers = [LayerCost("embed", 2 * d * S, cfg.vocab_size * d * PARAM_BYTES,
                             S * d * ACT_BYTES)]
-        for li in range(cfg.n_layers):
-            spec = cfg.pattern[li % len(cfg.pattern)]
+        specs = ([("dense", DENSE_LAYER)] * cfg.first_k_dense
+                 + [(None, cfg.pattern[li % len(cfg.pattern)])
+                    for li in range(cfg.n_layers)])
+        for li, (kind, spec) in enumerate(specs):
             p_count = cfg.layer_param_count(spec)
             p_active = cfg.layer_active_param_count(spec)
             flops = 2.0 * p_active * S
@@ -106,7 +112,7 @@ class LayerTable:
                 eff_ctx = S if win is None else min(S, win)
                 flops += 2.0 * 2.0 * S * eff_ctx * a.n_heads * a.head_dim / 2.0
             act = S * d * ACT_BYTES
-            layers.append(LayerCost(f"{spec.kind}{li}", flops,
+            layers.append(LayerCost(f"{kind or spec.kind}{li}", flops,
                                     p_count * PARAM_BYTES, act))
         layers.append(LayerCost("head", 2 * d * cfg.vocab_size * S,
                                 (0 if cfg.tie_embeddings else cfg.vocab_size * d * PARAM_BYTES),

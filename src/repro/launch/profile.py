@@ -44,8 +44,9 @@ def build_layer_fns(cfg, seq_len: int, key=None):
     """Per-layer callables matching ``LayerTable.from_model_config(cfg)``.
 
     Returns ``(layer_fns, make_input)`` for ``measure_layer_times``: one
-    jittable ``x -> y`` per table entry (embed, each of the ``n_layers``
-    block layers, head), bound to freshly-initialized params.  Block layers
+    jittable ``x -> y`` per table entry (embed, the leading dense layers,
+    each of the ``n_layers`` block layers, head), bound to freshly-initialized
+    params.  Block layers
     reuse one period's params per pattern slot — timing is weight-value
     independent, so one init covers all periods.
     """
@@ -53,6 +54,7 @@ def build_layer_fns(cfg, seq_len: int, key=None):
     import jax.numpy as jnp
 
     from repro.models.blocks import apply_layer
+    from repro.models.config import DENSE_LAYER
     from repro.models.model import _head_weight, embed_tokens, init_model
 
     if key is None:
@@ -64,9 +66,11 @@ def build_layer_fns(cfg, seq_len: int, key=None):
         return embed_tokens(params, tokens, cfg)
 
     fns = [embed_fn]
-    for li in range(cfg.n_layers):
-        spec = cfg.pattern[li % len(cfg.pattern)]
-        lp = period0["layers"][li % len(cfg.pattern)]
+    layers = [(DENSE_LAYER, lp) for lp in params.get("dense_prefix", ())]
+    layers += [(cfg.pattern[li % len(cfg.pattern)],
+                period0["layers"][li % len(cfg.pattern)])
+               for li in range(cfg.n_layers)]
+    for spec, lp in layers:
 
         def block_fn(x, lp=lp, spec=spec):
             B, S = x.shape[0], x.shape[1]

@@ -28,18 +28,23 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from repro import scopes
+
 from .module import ParallelCtx, NO_PARALLEL, dense_init, split_keys, vscan
 from .norms import init_rmsnorm, rmsnorm
-from .rotary import rope_cos_sin, apply_rope, apply_rope_partial
+from .rotary import YaRN, rope_cos_sin, apply_rope, apply_rope_partial
 
 NEG_INF = -1e30
 
 
 @dataclasses.dataclass(frozen=True)
 class MLAConfig:
-    """DeepSeek-V3 Multi-head Latent Attention dims (per head unless noted)."""
+    """DeepSeek Multi-head Latent Attention dims (per head unless noted).
 
-    q_lora_rank: int = 1536
+    ``q_lora_rank`` None: the query is one projection ``x @ wq`` with no
+    low-rank bottleneck (DeepSeek-V2-Lite)."""
+
+    q_lora_rank: int | None = 1536
     kv_lora_rank: int = 512
     qk_nope_dim: int = 128
     qk_rope_dim: int = 64
@@ -55,6 +60,7 @@ class AttentionConfig:
     window: int | None = None          # sliding-window size (None = full)
     softcap: float | None = None       # attn logit softcapping (Gemma2)
     mla: MLAConfig | None = None
+    rope_scaling: YaRN | None = None   # YaRN context extension (DeepSeek-V2)
     q_chunk: int = 1024
     kv_chunk: int = 1024
     # When tp > n_kv_heads the KV projections are *replicated* across tp
@@ -89,6 +95,22 @@ class AttentionConfig:
         head is sliced at attention-compute time."""
         return self.n_kv_heads
 
+    def rope(self, positions, dim: int):
+        """cos/sin tables of this config's rope at ``positions``."""
+        return rope_cos_sin(positions, dim, self.rope_theta, self.rope_scaling)
+
+    @property
+    def remat_query_chunks(self) -> bool:
+        """Latent attention runs at DeepSeek's long sequences, so its
+        backward keeps one query chunk's score blocks at a time."""
+        return self.mla is not None
+
+    def softmax_scale(self, qk_dim: int) -> float:
+        scale = qk_dim ** -0.5
+        if self.rope_scaling is not None:
+            scale *= self.rope_scaling.softmax_scale
+        return scale
+
 
 # ---------------------------------------------------------------------------
 # Parameter init
@@ -114,11 +136,18 @@ def init_mla_attention(key, d_model: int, cfg: AttentionConfig, dtype=jnp.float3
     ks = split_keys(key, 8)
     h = cfg.n_heads
     qk_dim = m.qk_nope_dim + m.qk_rope_dim
-    return {
+    if m.q_lora_rank is None:
+        # query path: one projection to per-head (nope+rope)
+        q = {"wq": dense_init(ks[0], (d_model, h * qk_dim), in_dim=d_model, dtype=dtype)}
+    else:
         # query path: down-proj -> norm -> up-proj to per-head (nope+rope)
-        "wq_a": dense_init(ks[0], (d_model, m.q_lora_rank), in_dim=d_model, dtype=dtype),
-        "q_norm": init_rmsnorm(ks[1], m.q_lora_rank, dtype),
-        "wq_b": dense_init(ks[2], (m.q_lora_rank, h * qk_dim), in_dim=m.q_lora_rank, dtype=dtype),
+        q = {
+            "wq_a": dense_init(ks[0], (d_model, m.q_lora_rank), in_dim=d_model, dtype=dtype),
+            "q_norm": init_rmsnorm(ks[1], m.q_lora_rank, dtype),
+            "wq_b": dense_init(ks[2], (m.q_lora_rank, h * qk_dim), in_dim=m.q_lora_rank, dtype=dtype),
+        }
+    return {
+        **q,
         # kv path: joint down-proj to latent (+ shared rope key)
         "wkv_a": dense_init(ks[3], (d_model, m.kv_lora_rank + m.qk_rope_dim), in_dim=d_model, dtype=dtype),
         "kv_norm": init_rmsnorm(ks[4], m.kv_lora_rank, dtype),
@@ -152,8 +181,15 @@ def blocked_causal_attention(
     softcap: float | None = None,
     q_chunk: int = 1024,
     kv_chunk: int = 1024,
+    remat_chunks: bool = False,
 ) -> jnp.ndarray:
-    """Exact causal attention, O(q_chunk × kv_chunk) live score memory."""
+    """Exact causal attention, O(q_chunk × kv_chunk) live score memory.
+
+    ``remat_chunks``: each query chunk is rematerialised in the backward
+    pass, so the backward holds one chunk's score blocks at a time instead of
+    every chunk's (O(q_chunk × S) rather than O(S² / 2) per layer), at the
+    cost of one more forward of the scores.
+    """
     B, S, Hq, D = q.shape
     Hkv = k.shape[2]
     Dv = v.shape[-1]
@@ -172,7 +208,7 @@ def blocked_causal_attention(
     qg = q.reshape(B, S, Hkv, G, D)
     out = jnp.zeros((B, S, Hkv, G, Dv), dtype=q.dtype)
 
-    for i in range(n_q):
+    def one_chunk(i, qg, k, v):
         q_lo = i * q_chunk
         q_hi = min(S, q_lo + q_chunk)
         Cq = q_hi - q_lo
@@ -210,7 +246,12 @@ def blocked_causal_attention(
         a0 = jnp.zeros((B, Hkv, G, Cq, Dv), jnp.float32)
         (m, l, acc), _ = vscan(kv_step, (m0, l0, a0), jnp.arange(n_kv))
         oi = (acc / jnp.maximum(l, 1e-37)[..., None]).transpose(0, 3, 1, 2, 4)
-        out = lax.dynamic_update_slice_in_dim(out, oi.astype(q.dtype), q_lo, axis=1)
+        return oi.astype(q.dtype)
+
+    for i in range(n_q):
+        chunk = partial(one_chunk, i)
+        oi = (jax.checkpoint(chunk) if remat_chunks else chunk)(qg, k, v)
+        out = lax.dynamic_update_slice_in_dim(out, oi, i * q_chunk, axis=1)
 
     return out.reshape(B, S, Hq, Dv)
 
@@ -299,12 +340,12 @@ def attention_forward(
     q = (x @ params["wq"]).reshape(B, S, h, d)
     k = _slice_kv((x @ params["wk"]).reshape(B, S, kvh, d), cfg, ctx)
     v = _slice_kv((x @ params["wv"]).reshape(B, S, kvh, d), cfg, ctx)
-    cos, sin = rope_cos_sin(positions, d, cfg.rope_theta)
+    cos, sin = cfg.rope(positions, d)
     q = apply_rope(q, cos, sin)
     k = apply_rope(k, cos, sin)
     o = blocked_causal_attention(
-        q, k, v, scale=d ** -0.5, window=cfg.window, softcap=cfg.softcap,
-        q_chunk=cfg.q_chunk, kv_chunk=cfg.kv_chunk,
+        q, k, v, scale=cfg.softmax_scale(d), window=cfg.window, softcap=cfg.softcap,
+        q_chunk=cfg.q_chunk, kv_chunk=cfg.kv_chunk, remat_chunks=cfg.remat_query_chunks,
     )
     out = o.reshape(B, S, h * d) @ params["wo"]
     return ctx.psum_tp(out)
@@ -331,11 +372,11 @@ def attention_decode(
     k = (x @ params["wk"]).reshape(B, kvh, d)
     v = (x @ params["wv"]).reshape(B, kvh, d)
     if jnp.ndim(position) == 1:
-        cos, sin = rope_cos_sin(position, d, cfg.rope_theta)    # (B, d/2)
+        cos, sin = cfg.rope(position, d)                        # (B, d/2)
         q = apply_rope(q[:, None], cos[:, None], sin[:, None])[:, 0]
         k = apply_rope(k[:, None], cos[:, None], sin[:, None])[:, 0]
     else:
-        cos, sin = rope_cos_sin(position[None], d, cfg.rope_theta)  # (1, d/2)
+        cos, sin = cfg.rope(position[None], d)                      # (1, d/2)
         q = apply_rope(q[:, None], cos[None], sin[None])[:, 0]
         k = apply_rope(k[:, None], cos[None], sin[None])[:, 0]
 
@@ -355,7 +396,7 @@ def attention_decode(
         win = cfg.window
     k_att = _slice_kv(cache["k"], cfg, ctx)
     v_att = _slice_kv(cache["v"], cfg, ctx)
-    o = decode_attention(q, k_att, v_att, cache_len, scale=d ** -0.5,
+    o = decode_attention(q, k_att, v_att, cache_len, scale=cfg.softmax_scale(d),
                          window=win, softcap=cfg.softcap, ctx=ctx)
     out = o.reshape(B, h * d) @ params["wo"]
     return ctx.psum_tp(out), cache
@@ -388,25 +429,37 @@ def _cache_insert(cache: dict, new: dict, position, ctx: ParallelCtx):
 
 
 # ---------------------------------------------------------------------------
-# MLA (DeepSeek-V3)
+# MLA (DeepSeek-V2 / V3)
 # ---------------------------------------------------------------------------
+
+
+def _mla_query(params, x):
+    """Per-head queries, flattened: through the low-rank bottleneck when the
+    config has one (``wq_a -> q_norm -> wq_b``), else ``x @ wq``."""
+    if "wq" in params:
+        return x @ params["wq"]
+    return rmsnorm(params["q_norm"], x @ params["wq_a"]) @ params["wq_b"]
 
 
 def mla_forward(params, x, positions, cfg: AttentionConfig, ctx: ParallelCtx):
     """MLA training/prefill: expand latent to per-head K/V (naive path)."""
+    with scopes.scope(scopes.MLA):
+        return _mla_forward(params, x, positions, cfg, ctx)
+
+
+def _mla_forward(params, x, positions, cfg: AttentionConfig, ctx: ParallelCtx):
     m = cfg.mla
     B, S, _ = x.shape
     h = cfg.n_heads
     qk_dim = m.qk_nope_dim + m.qk_rope_dim
 
-    cq = rmsnorm(params["q_norm"], x @ params["wq_a"])
-    q = (cq @ params["wq_b"]).reshape(B, S, h, qk_dim)
+    q = _mla_query(params, x).reshape(B, S, h, qk_dim)
 
     kv_a = x @ params["wkv_a"]                       # (B,S,rank+rope)
     c_kv = rmsnorm(params["kv_norm"], kv_a[..., : m.kv_lora_rank])
     k_rope = kv_a[..., m.kv_lora_rank:]              # (B,S,rope) shared across heads
 
-    cos, sin = rope_cos_sin(positions, m.qk_rope_dim, cfg.rope_theta)
+    cos, sin = cfg.rope(positions, m.qk_rope_dim)
     q = apply_rope_partial(q, cos, sin, m.qk_rope_dim)
     k_rope = apply_rope(k_rope[:, :, None, :], cos, sin)  # (B,S,1,rope)
 
@@ -415,8 +468,8 @@ def mla_forward(params, x, positions, cfg: AttentionConfig, ctx: ParallelCtx):
     k = jnp.concatenate([k_nope, jnp.broadcast_to(k_rope, (B, S, h, m.qk_rope_dim))], axis=-1)
 
     o = blocked_causal_attention(
-        q, k, v, scale=qk_dim ** -0.5, softcap=cfg.softcap,
-        q_chunk=cfg.q_chunk, kv_chunk=cfg.kv_chunk,
+        q, k, v, scale=cfg.softmax_scale(qk_dim), softcap=cfg.softcap,
+        q_chunk=cfg.q_chunk, kv_chunk=cfg.kv_chunk, remat_chunks=cfg.remat_query_chunks,
     )
     out = o.reshape(B, S, h * m.v_head_dim) @ params["wo"]
     return ctx.psum_tp(out)
@@ -434,18 +487,17 @@ def mla_decode(params, x, position, cache, cfg: AttentionConfig, ctx: ParallelCt
     h = cfg.n_heads
     rank = m.kv_lora_rank
 
-    cq = rmsnorm(params["q_norm"], x @ params["wq_a"])
-    q = (cq @ params["wq_b"]).reshape(B, h, m.qk_nope_dim + m.qk_rope_dim)
+    q = _mla_query(params, x).reshape(B, h, m.qk_nope_dim + m.qk_rope_dim)
     q_nope, q_rope = q[..., : m.qk_nope_dim], q[..., m.qk_nope_dim:]
 
     kv_a = x @ params["wkv_a"]
     c_kv = rmsnorm(params["kv_norm"], kv_a[..., :rank])          # (B, rank)
     if jnp.ndim(position) == 1:
-        cos, sin = rope_cos_sin(position, m.qk_rope_dim, cfg.rope_theta)  # (B, rope/2)
+        cos, sin = cfg.rope(position, m.qk_rope_dim)                       # (B, rope/2)
         q_rope = apply_rope(q_rope[:, None], cos[:, None], sin[:, None])[:, 0]
         k_rope = apply_rope(kv_a[..., rank:][:, None, None, :], cos[:, None], sin[:, None])[:, 0, 0]
     else:
-        cos, sin = rope_cos_sin(position[None], m.qk_rope_dim, cfg.rope_theta)
+        cos, sin = cfg.rope(position[None], m.qk_rope_dim)
         q_rope = apply_rope(q_rope[:, None], cos[None], sin[None])[:, 0]
         k_rope = apply_rope(kv_a[..., rank:][:, None, None, :], cos[None], sin[None])[:, 0, 0]
 
@@ -467,7 +519,7 @@ def mla_decode(params, x, position, cache, cfg: AttentionConfig, ctx: ParallelCt
         valid = pos < (position + 1)
         vmask = valid[None, None]
 
-    scale = (m.qk_nope_dim + m.qk_rope_dim) ** -0.5
+    scale = cfg.softmax_scale(m.qk_nope_dim + m.qk_rope_dim)
     s = jnp.einsum("bhr,bkr->bhk", q_lat, ckv_buf.astype(jnp.float32))
     s += jnp.einsum("bhd,bkd->bhk", q_rope.astype(jnp.float32), krope_buf.astype(jnp.float32))
     s = s * scale

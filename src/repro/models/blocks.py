@@ -4,7 +4,13 @@ A model is ``n_periods`` repetitions of ``cfg.pattern`` (a tuple of
 ``LayerSpec``).  Period parameters are stacked along a leading axis so the
 decoder body is a single ``lax.scan`` — this keeps the HLO size independent
 of depth and gives the pipeline runtime a natural unit to slice into stages
-(stage = consecutive periods).
+(stage = consecutive periods).  The ``cfg.first_k_dense`` leading dense
+layers (the dense prefix) come ahead of the stack, a tuple of unstacked
+layers that the first stage applies.
+
+Layer functions return ``(x, stats)``: ``stats`` sums, over the layers, the
+expert layers' balance loss (``aux``) and the rows their experts computed
+(``expert_rows``).
 
 All apply functions take the *localized* config (``ModelConfig.shard``) so
 the same code runs single-device and under shard_map tensor/expert
@@ -24,7 +30,7 @@ from repro import scopes
 
 from .attention import (AttentionConfig, attention_decode, attention_forward,
                         init_attention, init_attention_cache)
-from .config import LayerSpec, ModelConfig
+from .config import DENSE_LAYER, LayerSpec, ModelConfig
 from .mlp import init_mlp, mlp
 from .module import ParallelCtx, NO_PARALLEL, split_keys, vmap_init, vscan
 from .moe import init_moe, moe
@@ -101,6 +107,27 @@ def init_periods(key, cfg: ModelConfig):
     return vmap_init(init_period, key, cfg.n_periods, cfg)
 
 
+def init_dense_prefix(key, cfg: ModelConfig):
+    """The leading dense layers: a tuple of unstacked layer params."""
+    return tuple(init_layer(k, cfg, DENSE_LAYER)
+                 for k in split_keys(key, cfg.first_k_dense))
+
+
+# ---------------------------------------------------------------------------
+# Per-layer statistics
+# ---------------------------------------------------------------------------
+
+
+def zero_stats():
+    return {"aux": jnp.zeros((), jnp.float32),
+            "expert_rows": jnp.zeros((), jnp.float32)}
+
+
+def add_stats(a, b, weight=1.0):
+    """``a + weight * b``, leaf by leaf."""
+    return jax.tree.map(lambda x, y: x + y * weight, a, b)
+
+
 # ---------------------------------------------------------------------------
 # Forward (training / prefill)
 # ---------------------------------------------------------------------------
@@ -108,9 +135,9 @@ def init_periods(key, cfg: ModelConfig):
 
 def apply_layer(params, x, positions, cfg: ModelConfig, spec: LayerSpec,
                 ctx: ParallelCtx = NO_PARALLEL):
-    """Returns (x, aux_loss)."""
+    """Returns (x, stats)."""
     eps, zc = cfg.norm_eps, cfg.zero_centered_norm
-    aux = jnp.zeros((), jnp.float32)
+    stats = zero_stats()
     h = rmsnorm(params["norm1"], x, eps, zc)
     if spec.kind == "attn":
         with scopes.scope(scopes.ATTENTION):
@@ -125,40 +152,51 @@ def apply_layer(params, x, positions, cfg: ModelConfig, spec: LayerSpec,
     x = x + h.astype(x.dtype)
 
     if spec.mlp == "none":
-        return x, aux
+        return x, stats
     h = rmsnorm(params["norm2"], x, eps, zc)
     if spec.mlp == "mlp":
         with scopes.scope(scopes.MLP):
             h = mlp(params["mlp"], h, act=cfg.act, ctx=ctx)
     elif spec.mlp == "moe":
-        h, aux = moe(params["moe"], h, cfg.moe, cfg.moe.n_experts_global or cfg.moe.n_experts, ctx)
+        h, aux, rows = moe(params["moe"], h, cfg.moe, ctx)
+        stats = {"aux": aux, "expert_rows": rows}
     elif spec.mlp == "rwkv_cm":
         h, _ = rwkv_channel_mix(params["rwkv_cm"], h, ctx)
     if cfg.post_norms:
         h = rmsnorm(params["norm2_post"], h, eps, zc)
-    return x + h.astype(x.dtype), aux
+    return x + h.astype(x.dtype), stats
 
 
 def apply_period(params, x, positions, cfg: ModelConfig, ctx: ParallelCtx = NO_PARALLEL):
-    aux = jnp.zeros((), jnp.float32)
+    stats = zero_stats()
     for p, spec in zip(params["layers"], cfg.pattern):
-        x, a = apply_layer(p, x, positions, cfg, spec, ctx)
-        aux = aux + a
-    return x, aux
+        x, st = apply_layer(p, x, positions, cfg, spec, ctx)
+        stats = add_stats(stats, st)
+    return x, stats
+
+
+def apply_dense_prefix(layers, x, positions, cfg: ModelConfig,
+                       ctx: ParallelCtx = NO_PARALLEL, remat: bool = True):
+    """The leading dense layers, each rematerialised like a period."""
+    fn = partial(apply_layer, cfg=cfg, spec=DENSE_LAYER, ctx=ctx)
+    fn = jax.checkpoint(fn) if remat else fn
+    for p in layers:
+        x, _ = fn(p, x, positions)
+    return x
 
 
 def apply_periods(stacked, x, positions, cfg: ModelConfig,
                   ctx: ParallelCtx = NO_PARALLEL, remat: bool = True):
-    """Scan the stacked periods.  Returns (x, total_aux)."""
+    """Scan the stacked periods.  Returns (x, stats)."""
 
     def body(carry, period_params):
-        h, aux = carry
-        h, a = apply_period(period_params, h, positions, cfg, ctx)
-        return (h, aux + a), None
+        h, stats = carry
+        h, st = apply_period(period_params, h, positions, cfg, ctx)
+        return (h, add_stats(stats, st)), None
 
     fn = jax.checkpoint(body) if remat else body
-    (x, aux), _ = vscan(fn, (x, jnp.zeros((), jnp.float32)), stacked)
-    return x, aux
+    (x, stats), _ = vscan(fn, (x, zero_stats()), stacked)
+    return x, stats
 
 
 # ---------------------------------------------------------------------------
@@ -188,8 +226,7 @@ def decode_layer(params, x, position, state, cfg: ModelConfig, spec: LayerSpec,
         if spec.mlp == "mlp":
             h = mlp(params["mlp"], h, act=cfg.act, ctx=ctx)
         elif spec.mlp == "moe":
-            h, _ = moe(params["moe"], h, cfg.moe,
-                       cfg.moe.n_experts_global or cfg.moe.n_experts, ctx)
+            h, _, _ = moe(params["moe"], h, cfg.moe, ctx)
         elif spec.mlp == "rwkv_cm":
             h, state_c = rwkv_channel_mix_decode(params["rwkv_cm"], h, state["cm"], ctx)
         if cfg.post_norms:
@@ -221,6 +258,18 @@ def decode_periods(stacked, x, position, states, cfg: ModelConfig,
 
     x, new_states = vscan(body, x, (stacked, states))
     return x, new_states
+
+
+def decode_dense_prefix(layers, x, position, states, cfg: ModelConfig,
+                        ctx: ParallelCtx = NO_PARALLEL):
+    """Decode through the leading dense layers; ``states`` stacked over
+    them like period states."""
+    new_states = []
+    for i, p in enumerate(layers):
+        st = jax.tree.map(lambda s: s[i], states)
+        x, ns = decode_layer(p, x, position, st, cfg, DENSE_LAYER, ctx)
+        new_states.append(ns)
+    return x, jax.tree.map(lambda *s: jnp.stack(s), *new_states)
 
 
 # ---------------------------------------------------------------------------
@@ -259,3 +308,10 @@ def init_period_states(batch: int, max_len: int, cfg: ModelConfig, dtype,
     one = tuple(init_layer_state(batch, max_len, cfg, s, dtype, seq_shards)
                 for s in cfg.pattern)
     return jax.tree.map(lambda x: jnp.broadcast_to(x, (cfg.n_periods, *x.shape)).copy(), one)
+
+
+def init_dense_prefix_states(batch: int, max_len: int, cfg: ModelConfig, dtype,
+                             seq_shards: int = 1):
+    """Decode states of the leading dense layers, stacked over them."""
+    one = init_layer_state(batch, max_len, cfg, DENSE_LAYER, dtype, seq_shards)
+    return jax.tree.map(lambda x: jnp.broadcast_to(x, (cfg.first_k_dense, *x.shape)).copy(), one)

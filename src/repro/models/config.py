@@ -28,8 +28,15 @@ class LayerSpec:
     full_attention: bool = True      # False => use `window`
 
 
+DENSE_LAYER = LayerSpec(kind="attn", mlp="mlp")
+
+
 @dataclasses.dataclass(frozen=True)
 class ModelConfig:
+    """``n_layers`` counts the layers of the uniform period stack; the
+    ``first_k_dense`` leading dense layers (attention + dense MLP of
+    ``d_ff``, DeepSeek's ``first_k_dense_replace``) come ahead of it."""
+
     name: str
     n_layers: int
     d_model: int
@@ -51,6 +58,7 @@ class ModelConfig:
     n_codebooks: int = 1             # MusicGen: parallel codebook streams
     prefix_len: int = 0              # VLM/audio stub: prepended frontend embeddings
     mtp_depth: int = 0               # DeepSeek-V3 multi-token prediction heads
+    first_k_dense: int = 0           # leading dense layers ahead of the stack
     # dtypes
     param_dtype: str = "float32"
     compute_dtype: str = "float32"
@@ -83,7 +91,10 @@ class ModelConfig:
             if a.mla is not None:
                 m = a.mla
                 qk = m.qk_nope_dim + m.qk_rope_dim
-                n += d * m.q_lora_rank + m.q_lora_rank * a.n_heads * qk
+                if m.q_lora_rank is None:
+                    n += d * a.n_heads * qk
+                else:
+                    n += d * m.q_lora_rank + m.q_lora_rank * a.n_heads * qk
                 n += d * (m.kv_lora_rank + m.qk_rope_dim)
                 n += m.kv_lora_rank * a.n_heads * (m.qk_nope_dim + m.v_head_dim)
                 n += a.n_heads * m.v_head_dim * d
@@ -102,7 +113,7 @@ class ModelConfig:
         if spec.mlp == "mlp":
             n += 3 * d * self.d_ff
         elif spec.mlp == "moe" and self.moe is not None:
-            n += d * self.moe.n_experts
+            n += d * self.moe.router_width
             n += self.moe.n_experts * 3 * d * self.moe.d_ff
             n += self.moe.n_shared_experts * 3 * d * self.moe.d_ff
         elif spec.mlp == "rwkv_cm":
@@ -111,24 +122,23 @@ class ModelConfig:
         return n
 
     def layer_active_param_count(self, spec: LayerSpec) -> int:
-        """Params touched per token (MoE: top_k + shared experts only)."""
+        """Params touched per token (MoE: the held share of top_k routed
+        experts, top_k * held / routed, beside the shared experts)."""
         if spec.mlp != "moe" or self.moe is None:
             return self.layer_param_count(spec)
-        n = self.layer_param_count(spec)
-        n -= self.moe.n_experts * 3 * self.d_model * self.moe.d_ff
-        n += (self.moe.top_k + self.moe.n_shared_experts) * 3 * self.d_model * self.moe.d_ff
+        m = self.moe
+        expert = 3 * self.d_model * m.d_ff
+        n = self.layer_param_count(spec) - m.n_experts * expert
+        return n + m.top_k * m.n_experts * expert // m.router_width
+
+    def _count(self, per_layer) -> int:
+        n = sum(per_layer(s) for s in self.pattern) * self.n_periods
+        n += per_layer(DENSE_LAYER) * self.first_k_dense
+        n += self.vocab_size * self.d_model * self.n_codebooks * (1 if self.tie_embeddings else 2)
         return n
 
     def param_count(self) -> int:
-        per_period = sum(self.layer_param_count(s) for s in self.pattern)
-        n = per_period * self.n_periods
-        n += self.vocab_size * self.d_model * self.n_codebooks  # embed
-        if not self.tie_embeddings:
-            n += self.vocab_size * self.d_model * self.n_codebooks
-        return n
+        return self._count(self.layer_param_count)
 
     def active_param_count(self) -> int:
-        per_period = sum(self.layer_active_param_count(s) for s in self.pattern)
-        n = per_period * self.n_periods
-        n += self.vocab_size * self.d_model * self.n_codebooks * (1 if self.tie_embeddings else 2)
-        return n
+        return self._count(self.layer_active_param_count)

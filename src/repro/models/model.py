@@ -1,4 +1,5 @@
-"""CausalLM assembly: embedding -> scanned periods -> norm -> head (+MTP).
+"""CausalLM assembly: embedding -> dense prefix -> scanned periods -> norm
+-> head (+MTP).
 
 Covers every assigned architecture:
 
@@ -23,8 +24,10 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from .blocks import (apply_period, apply_periods, decode_periods, init_period,
-                     init_period_states, init_periods)
+from .blocks import (apply_dense_prefix, apply_period, apply_periods,
+                     decode_dense_prefix, decode_periods, init_dense_prefix,
+                     init_dense_prefix_states, init_period, init_period_states,
+                     init_periods)
 from .config import ModelConfig
 from .module import NO_PARALLEL, ParallelCtx, dense_init, embed_init, split_keys, vscan
 from .norms import init_rmsnorm, rmsnorm
@@ -45,6 +48,8 @@ def init_model(key, cfg: ModelConfig):
         "periods": init_periods(ks[1], cfg),
         "final_norm": init_rmsnorm(ks[2], d, dtype, cfg.zero_centered_norm),
     }
+    if cfg.first_k_dense:
+        params["dense_prefix"] = init_dense_prefix(jax.random.fold_in(key, 15), cfg)
     if not cfg.tie_embeddings:
         params["head"] = dense_init(
             ks[3], (cfg.n_codebooks, d, v) if cfg.n_codebooks > 1 else (d, v),
@@ -136,7 +141,7 @@ def model_forward(params, tokens, cfg: ModelConfig, ctx: ParallelCtx = NO_PARALL
                   prefix: jnp.ndarray | None = None, remat: bool = True):
     """Backbone forward.  tokens (B,S) or (B,CB,S); prefix (B,P,F) stub embeds.
 
-    Returns (h (B, S_total, D), aux_loss, positions).
+    Returns (h (B, S_total, D), stats, positions).
     """
     x = embed_tokens(params, tokens, cfg)
     B = x.shape[0]
@@ -146,8 +151,10 @@ def model_forward(params, tokens, cfg: ModelConfig, ctx: ParallelCtx = NO_PARALL
         x = jnp.concatenate([px, x], axis=1)
     S_total = x.shape[1]
     positions = jnp.broadcast_to(jnp.arange(S_total, dtype=jnp.int32), (B, S_total))
-    h, aux = apply_periods(params["periods"], x, positions, cfg, ctx, remat=remat)
-    return h, aux, positions
+    if cfg.first_k_dense:
+        x = apply_dense_prefix(params["dense_prefix"], x, positions, cfg, ctx, remat)
+    h, stats = apply_periods(params["periods"], x, positions, cfg, ctx, remat=remat)
+    return h, stats, positions
 
 
 def loss_fn(params, batch, cfg: ModelConfig, ctx: ParallelCtx = NO_PARALLEL,
@@ -157,7 +164,8 @@ def loss_fn(params, batch, cfg: ModelConfig, ctx: ParallelCtx = NO_PARALLEL,
     Returns (loss, metrics dict).
     """
     tokens = batch["tokens"]
-    h, aux, _ = model_forward(params, tokens, cfg, ctx, batch.get("prefix"), remat)
+    h, stats, _ = model_forward(params, tokens, cfg, ctx, batch.get("prefix"), remat)
+    aux = stats["aux"]
     h = rmsnorm(params["final_norm"], h, cfg.norm_eps, cfg.zero_centered_norm)
     if cfg.prefix_len > 0:
         h = h[:, cfg.prefix_len:]         # loss on text positions only
@@ -181,6 +189,8 @@ def loss_fn(params, batch, cfg: ModelConfig, ctx: ParallelCtx = NO_PARALLEL,
     loss = total / jnp.maximum(count, 1.0)
     metrics = {"ce": loss, "aux": aux, "acc": correct / jnp.maximum(count, 1.0),
                "tokens": count}
+    if cfg.moe is not None:
+        metrics["expert_rows"] = stats["expert_rows"]
     loss = loss + aux
 
     if cfg.mtp_depth > 0 and cfg.n_codebooks == 1:
@@ -223,7 +233,14 @@ def _mtp_loss(params, h, tokens, cfg: ModelConfig, ctx, mask, ce_chunk):
 
 def init_decode_states(batch: int, max_len: int, cfg: ModelConfig,
                        seq_shards: int = 1):
-    return init_period_states(batch, max_len, cfg, cfg.cdtype, seq_shards)
+    """Period states; with a dense prefix ``{"dense_prefix", "periods"}``.
+    Every leaf is stacked over layers, batch on axis 1."""
+    states = init_period_states(batch, max_len, cfg, cfg.cdtype, seq_shards)
+    if not cfg.first_k_dense:
+        return states
+    return {"dense_prefix": init_dense_prefix_states(batch, max_len, cfg, cfg.cdtype,
+                                                     seq_shards),
+            "periods": states}
 
 
 def decode_step(params, token, position, states, cfg: ModelConfig,
@@ -243,7 +260,13 @@ def decode_step(params, token, position, states, cfg: ModelConfig,
         x = x * jnp.asarray(cfg.d_model ** 0.5, x.dtype)
     x = x.astype(cfg.cdtype)
 
+    if cfg.first_k_dense:
+        x, prefix_states = decode_dense_prefix(params["dense_prefix"], x, position,
+                                               states["dense_prefix"], cfg, ctx)
+        states = states["periods"]
     h, new_states = decode_periods(params["periods"], x, position, states, cfg, ctx)
+    if cfg.first_k_dense:
+        new_states = {"dense_prefix": prefix_states, "periods": new_states}
     h = rmsnorm(params["final_norm"], h, cfg.norm_eps, cfg.zero_centered_norm)
 
     if cfg.n_codebooks > 1:

@@ -19,7 +19,10 @@ Asteroid's hybrid pipeline parallelism on the refined TPU mesh
   is stage-sharded instead of wasted;
 * the stage body is remat'ed (`jax.checkpoint`), bounding resident
   activations to the stage *input* per in-flight micro-batch — the SPMD
-  realization of the paper's O(K_p) 1F1B memory bound (DESIGN.md §4).
+  realization of the paper's O(K_p) 1F1B memory bound (DESIGN.md §4);
+* a model's leading dense layers (``cfg.first_k_dense``) run in the first
+  stage's slot of the tick scan, once per micro-batch, ahead of its periods;
+  on more than one stage under a ``lax.cond`` that only the first takes.
 
 The paper's planner picks the stage count; ``pad_periods`` pads the period
 stack with zero (identity) layers when stages don't divide the period count.
@@ -39,7 +42,8 @@ from repro import scopes
 from repro.core.schedule import scan_ticks
 from repro.kernels.quant_transfer import dequantize_op, quantize_op
 from repro.distributed.mesh import MeshPlan
-from repro.models.blocks import apply_period, shard_config
+from repro.models.blocks import (add_stats, apply_dense_prefix, apply_period,
+                                 shard_config, zero_stats)
 from repro.models.config import ModelConfig
 from repro.models.model import MTP_WEIGHT
 from repro.models.module import ParallelCtx, pcast_varying, vary_all
@@ -134,22 +138,37 @@ def arrange_periods(periods, stage_periods):
 
 def _stage_fn(periods_local, period_mask_local, x, positions, cfg_local,
               ctx: ParallelCtx, remat: bool):
-    """Apply this stage's local periods (scan), masking padded periods' aux."""
+    """Apply this stage's local periods (scan), masking padded periods'
+    stats."""
 
     def body(carry, inputs):
-        h, aux = carry
+        h, stats = carry
         pp, valid = inputs
-        h, a = apply_period(pp, h, positions, cfg_local, ctx)
-        return vary_all((h, aux + a * valid)), None
+        h, st = apply_period(pp, h, positions, cfg_local, ctx)
+        return vary_all((h, add_stats(stats, st, valid))), None
 
     fn = jax.checkpoint(body) if remat else body
     with scopes.scope(scopes.STAGE):
-        # params are stage-varying (and MoE aux data-varying), so the carry
-        # is typed varying over all manual axes
-        (x, aux) = vary_all((x, jnp.zeros((), jnp.float32)))
-        (x, aux), _ = lax.scan(fn, (x, aux),
-                               (periods_local, period_mask_local))
-    return x, aux
+        # params are stage-varying (and MoE stats data-varying), so the
+        # carry is typed varying over all manual axes
+        (x, stats) = vary_all((x, zero_stats()))
+        (x, stats), _ = lax.scan(fn, (x, stats),
+                                 (periods_local, period_mask_local))
+    return x, stats
+
+
+def _first_stage_prefix(dense_prefix, x, first, positions, cfg_local,
+                        ctx: ParallelCtx, remat: bool, n_stages: int):
+    """The leading dense layers on ``x`` where ``first`` (the first stage,
+    on a tick that carries a micro-batch); with one stage, always."""
+    def apply(h):
+        return vary_all(apply_dense_prefix(dense_prefix, h, positions,
+                                           cfg_local, ctx, remat))
+
+    with scopes.scope(scopes.STAGE):
+        if n_stages == 1:
+            return apply(x)
+        return lax.cond(first, apply, vary_all, x)
 
 
 # ---------------------------------------------------------------------------
@@ -198,12 +217,15 @@ compressed_ppermute.defvjp(_cperm_fwd, _cperm_bwd)
 def pipeline_apply(periods_local, period_mask_local, x_micro, positions,
                    cfg_local: ModelConfig, ctx: ParallelCtx, n_stages: int,
                    remat: bool = True, double_buffer: bool = False,
-                   compress: str = "none", quant_tile: int = 256):
+                   compress: str = "none", quant_tile: int = 256,
+                   dense_prefix=None):
     """Run M micro-batches through the stage pipeline.
 
     x_micro: (M, mb, S, D) — identical on every stage (batch-sharded over
     dp axes only); returns (outs (M, mb, S, D) valid on the last stage,
-    aux_loss — sum over this stage's real ticks).
+    stats — summed over this stage's real ticks).  ``dense_prefix``: the
+    model's leading dense layers, which the first stage applies to each
+    micro-batch ahead of its periods.
 
     ``double_buffer=False`` is the synchronous pipeline: each tick computes
     a stage forward and then ``ppermute``s the output, so the boundary
@@ -239,12 +261,11 @@ def pipeline_apply(periods_local, period_mask_local, x_micro, positions,
                 return compressed_ppermute(x, perm, compress, quant_tile)
             return lax.ppermute(x, "stage", perm)
 
-    state0, outs0, aux0 = vary_all(
-        (jnp.zeros_like(x_micro[0]), jnp.zeros_like(x_micro),
-         jnp.zeros((), jnp.float32)))
+    state0, outs0, stats0 = vary_all(
+        (jnp.zeros_like(x_micro[0]), jnp.zeros_like(x_micro), zero_stats()))
 
-    def compute(recv, outs, aux, t):
-        """One stage forward on this tick's input; masked aux/outs update.
+    def compute(recv, outs, stats, t):
+        """One stage forward on this tick's input; masked stats/outs update.
 
         Shared by both pipeline variants: only *when* the boundary transfer
         runs differs, never the per-micro-batch math (the staleness-0
@@ -254,42 +275,45 @@ def pipeline_apply(periods_local, period_mask_local, x_micro, positions,
                         lax.dynamic_index_in_dim(x_micro, jnp.clip(t, 0, M - 1),
                                                  0, keepdims=False),
                         recv)
+        if dense_prefix is not None:
+            inp = _first_stage_prefix(dense_prefix, inp, (stage == 0) & (t < M),
+                                      positions, cfg_local, ctx, remat, P_st)
         out, a = _stage_fn(periods_local, period_mask_local, inp, positions,
                            cfg_local, ctx, remat)
-        # only ticks carrying a real micro-batch contribute aux loss
+        # only ticks carrying a real micro-batch contribute stats
         valid = (t >= hop * stage) & (t < hop * stage + M)
-        aux = aux + jnp.where(valid, a, 0.0)
+        stats = jax.tree.map(lambda s, x: s + jnp.where(valid, x, 0.0), stats, a)
         oidx = t - hop * (P_st - 1)
         outs = jnp.where(
             (stage == P_st - 1) & (oidx >= 0),
             lax.dynamic_update_index_in_dim(outs, out, jnp.clip(oidx, 0, M - 1), 0),
             outs)
-        return out, outs, aux
+        return out, outs, stats
 
     if double_buffer:
         def tick(carry, t):
-            send, recv, outs, aux = carry
+            send, recv, outs, stats = carry
             # transfer of the PREVIOUS tick's output: independent of this
             # tick's compute, so the two streams overlap
             arrived = boundary(send)
-            out, outs, aux = compute(recv, outs, aux, t)
-            return vary_all((out, arrived, outs, aux)), None
+            out, outs, stats = compute(recv, outs, stats, t)
+            return vary_all((out, arrived, outs, stats)), None
 
-        carry0 = vary_all((state0, state0, outs0, aux0))
+        carry0 = vary_all((state0, state0, outs0, stats0))
     else:
         def tick(carry, t):
-            state, outs, aux = carry
-            out, outs, aux = compute(state, outs, aux, t)
+            state, outs, stats = carry
+            out, outs, stats = compute(state, outs, stats, t)
             nxt = boundary(out)
-            return vary_all((nxt, outs, aux)), None
+            return vary_all((nxt, outs, stats)), None
 
-        carry0 = (state0, outs0, aux0)
+        carry0 = (state0, outs0, stats0)
 
     with scopes.scope(scopes.PIPELINE):
         final, _ = lax.scan(tick, carry0,
                             jnp.arange(scan_ticks(P_st, M, double_buffer)))
-    outs, aux = final[-2], final[-1]
-    return outs, aux
+    outs, stats = final[-2], final[-1]
+    return outs, stats
 
 
 # ---------------------------------------------------------------------------
@@ -450,12 +474,13 @@ def spmd_loss_fn(spec: TrainSpec):
             # embedding-gradient path) is reduced once instead of per tick
             with scopes.scope(scopes.EMBED):
                 x_micro = vary_all(x_micro)
-        outs, aux = pipeline_apply(params["periods"], mask_local,
+        outs, stats = pipeline_apply(params["periods"], mask_local,
                                    x_micro, positions, cfg_local, ctx,
                                    plan.stage, spec.remat,
                                    double_buffer=spec.double_buffer,
                                    compress=spec.compress,
-                                   quant_tile=spec.quant_tile)
+                                   quant_tile=spec.quant_tile,
+                                   dense_prefix=params.get("dense_prefix"))
 
         # ---- redistribute last-stage outputs across stages ----------------
         # Every stage holds an `outs` buffer but only the last stage's is
@@ -563,7 +588,7 @@ def spmd_loss_fn(spec: TrainSpec):
             cnt_sum = allsum(cnt_sum)
             # aux: sum over stages (layers), mean over dp replicas AND over the
             # M micro-batches (each tick computes a mean-style aux estimate)
-            aux = allsum(aux) / (plan.dp_shards * M)
+            aux = allsum(stats["aux"]) / (plan.dp_shards * M)
             ce = loss_sum / jnp.maximum(cnt_sum, 1.0)
             loss = ce + aux
             if cfg.mtp_depth > 0 and cfg.n_codebooks == 1 and cfg.prefix_len == 0:
@@ -572,9 +597,19 @@ def spmd_loss_fn(spec: TrainSpec):
             else:
                 mtp = jnp.zeros(())
         metrics = {"ce": ce, "aux": aux, "mtp": mtp, "tokens": cnt_sum}
+        if cfg.moe is not None:
+            metrics["expert_rows"] = allsum(stats["expert_rows"])
         return loss, metrics
 
     return fn
+
+
+def metric_pspecs(cfg: ModelConfig) -> dict:
+    """PartitionSpecs of the step's ``metrics`` (replicated scalars)."""
+    names = ["ce", "aux", "mtp", "tokens"]
+    if cfg.moe is not None:
+        names.append("expert_rows")
+    return {k: P() for k in names}
 
 
 def batch_pspecs(cfg: ModelConfig) -> dict:

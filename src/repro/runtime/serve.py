@@ -94,6 +94,10 @@ class ServeSpec:
 
 def spmd_decode_fn(spec: ServeSpec):
     cfg = spec.cfg
+    if cfg.first_k_dense:
+        raise NotImplementedError(
+            "the pipelined decode step holds no leading dense layers; decode "
+            "such a model with models.model.decode_step")
     cfg_local = spec.cfg_local
     plan = spec.plan
     P_st = plan.stage
@@ -288,7 +292,8 @@ def build_prefill_step(cfg: ModelConfig, production_mesh: Mesh, *,
         x_micro = x.reshape(M, mb, S_tot, cfg.d_model)
         from repro.runtime.pipeline import pipeline_apply as _pa
         outs, _ = _pa(params["periods"], mask_local, x_micro, positions,
-                      cfg_local, ctx, plan.stage, remat=False)
+                      cfg_local, ctx, plan.stage, remat=False,
+                      dense_prefix=params.get("dense_prefix"))
         h_last = outs[:, :, -1, :].reshape(B_loc, cfg.d_model)
         if plan.stage > 1:
             st = lax.axis_index("stage")

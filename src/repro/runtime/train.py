@@ -30,8 +30,8 @@ from repro.models.model import init_model
 from repro.models.module import vary_all
 from repro.optim import AdamW
 
-from .pipeline import (TrainSpec, arrange_periods, batch_pspecs, pad_periods,
-                       spmd_loss_fn)
+from .pipeline import (TrainSpec, arrange_periods, batch_pspecs, metric_pspecs,
+                       pad_periods, spmd_loss_fn)
 
 
 def vocab_axes(cfg: ModelConfig) -> dict:
@@ -499,7 +499,7 @@ def _assemble_train_step(cfg: ModelConfig, production_mesh: Mesh,
     bspecs = batch_pspecs(cfg)
 
     spmd = spmd_loss_fn(spec)
-    metrics_sp = {"ce": P(), "aux": P(), "mtp": P(), "tokens": P()}
+    metrics_sp = metric_pspecs(cfg)
     sharded_loss = jax.shard_map(spmd, mesh=mesh,
                              in_specs=(pspecs, bspecs),
                              out_specs=(P(), metrics_sp))

@@ -13,7 +13,13 @@ pipeline      the tick scan of ``pipeline.pipeline_apply``: input select,
               ``outs`` update, the scan's saved residuals
 stage         ``pipeline._stage_fn``: one stage's period scan
 attention     ``models.blocks.apply_layer``'s attention
-mlp           ``models.blocks.apply_layer``'s dense MLP
+mla           inside ``attention``: ``models.attention.mla_forward``, the
+              latent attention (projections, rope, the blocked softmax)
+mlp           ``models.blocks.apply_layer``'s dense MLP, and an expert
+              layer's shared experts
+router        ``models.moe.moe``: router logits, top-k, the balance loss,
+              the sort of (token, expert) pairs and the weighted combine
+experts       ``models.moe.moe``: the grouped matmuls of the held experts
 boundary      the stage-boundary ``ppermute`` (or its compressed form)
 embed         the vocab-parallel embedding and its gradient
 redistribute  the ``all_to_all`` of the last stage's outputs over stages
@@ -33,7 +39,10 @@ PREFIX = "asteroid"
 PIPELINE = "pipeline"
 STAGE = "stage"
 ATTENTION = "attention"
+MLA = "mla"
 MLP = "mlp"
+ROUTER = "router"
+EXPERTS = "experts"
 BOUNDARY = "boundary"
 EMBED = "embed"
 REDISTRIBUTE = "redistribute"
@@ -41,8 +50,12 @@ HEAD_CE = "head_ce"
 GRAD_REDUCE = "grad_reduce"
 OPTIMIZER = "optimizer"
 
+# the scopes of every train step
 ALL = (PIPELINE, STAGE, ATTENTION, MLP, BOUNDARY, EMBED, REDISTRIBUTE,
        HEAD_CE, GRAD_REDUCE, OPTIMIZER)
+
+# the scopes of a model with latent attention or an expert layer
+MODEL_SPECIFIC = (MLA, ROUTER, EXPERTS)
 
 # host span (``jax.profiler.TraceAnnotation``) around a batch's packing and
 # placement on the mesh
@@ -50,5 +63,6 @@ SHARD_BATCH_SPAN = f"{PREFIX}.shard_batch"
 
 
 def scope(name: str):
-    """The named scope ``asteroid/<name>``; ``name`` is one of ``ALL``."""
+    """The named scope ``asteroid/<name>``; ``name`` is one of ``ALL`` or
+    ``MODEL_SPECIFIC``."""
     return jax.named_scope(f"{PREFIX}/{name}")

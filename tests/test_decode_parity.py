@@ -2,8 +2,8 @@
 
 For every architecture family, step-by-step decoding with the KV/state cache
 must reproduce the full-sequence forward logits.  MoE archs use a capacity
-factor large enough that no token drops (capacity-based dropping is the one
-legitimate train/decode divergence)."""
+factor large enough that no token drops (capacity-based dropping, on the
+expert-parallel path only, is the one legitimate train/decode divergence)."""
 
 import dataclasses
 
@@ -18,7 +18,7 @@ from repro.models.norms import rmsnorm
 
 ARCHS = ["phi3-mini-3.8b", "gemma-2b", "gemma2-2b", "rwkv6-7b",
          "jamba-1.5-large-398b", "deepseek-v3-671b", "phi3.5-moe-42b-a6.6b",
-         "musicgen-large", "internvl2-2b", "deepseek-7b"]
+         "musicgen-large", "internvl2-2b", "deepseek-7b", "deepseek-v2-lite-16b"]
 
 B, S = 2, 32
 

@@ -44,7 +44,8 @@ def test_select_devices_takes_first_n_and_refuses_more(restore_cache_config):
         select_devices(len(devs) + 1)
 
 
-def test_train_main_plan_matches_reference_loss(restore_cache_config):
+@pytest.mark.parametrize("arch", ["phi3-mini-3.8b", "deepseek-v2-lite-16b"])
+def test_train_main_plan_matches_reference_loss(restore_cache_config, arch):
     """``launch.train.main(argv)`` through the planner: finite per-step
     losses, the first step's loss equals the plain model's loss on the
     same seed and batch, and the second step's equals the plain model's
@@ -54,14 +55,14 @@ def test_train_main_plan_matches_reference_loss(restore_cache_config):
     from repro.launch import train
     from repro.models.model import init_model, loss_fn
 
-    res = train.main(["--smoke", "--plan", "--env", "v5e", "--devices", "1",
-                      "--steps", "2", "--seq", "32", "--global-batch", "4",
-                      "--log-every", "1"])
+    res = train.main(["--arch", arch, "--smoke", "--plan", "--env", "v5e",
+                      "--devices", "1", "--steps", "2", "--seq", "32",
+                      "--global-batch", "4", "--log-every", "1"])
     assert [s for s, _, _ in res["log"]] == [0, 1]
     assert all(math.isfinite(loss) and t > 0 for _, loss, t in res["log"])
     assert math.isfinite(res["loss"]) and res["tok_s"] > 0
 
-    cfg = get_smoke_config("phi3-mini-3.8b")
+    cfg = get_smoke_config(arch)
     ds = SyntheticLM(cfg.vocab_size, 32)
     b0, b1 = ({k: jnp.asarray(v) for k, v in ds.batch(i, 4).items()}
               for i in (0, 1))

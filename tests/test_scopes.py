@@ -40,13 +40,15 @@ print(json.dumps(sorted(compiled_scopes(jax.devices()[:2], ((0, 2), (2, 3))))))
 """
 
 
-def compiled_scopes(devices, stage_periods=None) -> set:
+def compiled_scopes(devices, stage_periods=None,
+                    arch="phi3-mini-3.8b") -> set:
     """Innermost scope names of the ops in the compiled HLO of the smoke
-    phi3 train step on a (1, len(devices)) mesh, one stage per device."""
+    train step of ``arch`` on a (1, len(devices)) mesh, one stage per
+    device."""
     from repro.data import SyntheticLM
     from repro.runtime.train import build_train_step, init_train_state
 
-    cfg = get_smoke_config("phi3-mini-3.8b")
+    cfg = get_smoke_config(arch)
     if stage_periods is not None:
         cfg = cfg.replace(n_layers=stage_periods[-1][1])
     n = len(devices)

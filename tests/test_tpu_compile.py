@@ -4,7 +4,8 @@ The chip's compiler refuses what interpret mode and the CPU accept: block
 shapes off the sublane tiling, a program larger than the chip's memory.
 These tests compile, at phi3-mini-3.8b's published widths, the quantized
 boundary-transfer kernels the runtime dispatches on TPU and one decoder
-layer's forward and backward pass.
+layer's forward and backward pass, and, at DeepSeek-V2-Lite's, one expert
+layer's, whose held experts run as grouped-matmul kernels.
 
 The topology is described inside a fixture and never while a module is
 imported: one process at a time may load the TPU library, and every test
@@ -91,8 +92,8 @@ def test_phi3_layer_fwd_bwd_fits_one_v5e(one_chip, no_persistent_cache):
 
     def fwd_bwd(p, x, pos):
         def loss(p, x):
-            y, aux = apply_period(p, x, pos, cfg)
-            return jnp.sum(y.astype(jnp.float32)) + aux
+            y, stats = apply_period(p, x, pos, cfg)
+            return jnp.sum(y.astype(jnp.float32)) + stats["aux"]
         return jax.grad(loss, argnums=(0, 1))(p, x)
 
     compiled = jax.jit(fwd_bwd).lower(params, x, pos).compile()
@@ -100,3 +101,42 @@ def test_phi3_layer_fwd_bwd_fits_one_v5e(one_chip, no_persistent_cache):
     used = (ma.argument_size_in_bytes + ma.output_size_in_bytes
             + ma.temp_size_in_bytes - ma.alias_size_in_bytes)
     assert 0 < used < V5E_HBM_BYTES, ma
+
+
+def test_expert_layer_compiles_to_scoped_grouped_matmuls(topo, no_persistent_cache):
+    """One DeepSeek-V2-Lite expert layer (8 of 64 experts held), forward
+    and backward inside a shard_map as the stage runs it: the held experts'
+    matmuls are the ``megablox`` kernels (three forward, six backward), each
+    under the ``experts`` scope, so a trace puts their time there."""
+    import dataclasses
+    import re
+
+    import numpy as np
+    from jax.sharding import Mesh, PartitionSpec as P
+
+    from repro import scopes
+
+    cfg = get_config("deepseek-v2-lite-16b")
+    cfg = dataclasses.replace(cfg, moe=dataclasses.replace(cfg.moe, n_experts=8))
+    mesh = Mesh(np.array(topo.devices[:1]), ("model",))
+    rep = jax.sharding.NamedSharding(mesh, P())
+    abstract = jax.eval_shape(lambda k: init_period(k, cfg), jax.random.PRNGKey(0))
+    params = jax.tree.map(lambda a: _on(rep, a.shape, a.dtype), abstract)
+    x = _on(rep, (1, 512, cfg.d_model), cfg.cdtype)
+    pos = _on(rep, (1, 512), jnp.int32)
+
+    @jax.jit
+    @jax.shard_map(mesh=mesh, in_specs=(P(), P(), P()), out_specs=P())
+    def fwd_bwd(p, x, pos):
+        def loss(p, x):
+            y, stats = apply_period(p, x, pos, cfg)
+            return jnp.sum(y.astype(jnp.float32)) + stats["aux"]
+        return jax.lax.psum(jax.grad(loss, argnums=(0, 1))(p, x), "model")
+
+    text = fwd_bwd.lower(params, x, pos).compile().as_text()
+    kernels = [re.search(r'op_name="([^"]*)"', line).group(1)
+               for line in text.splitlines()
+               if re.search(r"custom_call_target=\"tpu_custom_call\"", line)
+               and "gmm" in line]
+    assert len(kernels) == 9, kernels
+    assert all(f"{scopes.PREFIX}/{scopes.EXPERTS}/" in k for k in kernels), kernels
